@@ -181,9 +181,9 @@ def run_paged_attention_sweep(small: bool = False):
         nb = b * mb + 1              # every slot fully backed + trash block
         q = jax.random.normal(key, (b, 1, kh * g, dh), jnp.float32)
         kp = jax.random.normal(jax.random.fold_in(key, w),
-                               (nb, bs, kh, dh), jnp.float32)
+                               (nb, kh, bs, dh), jnp.float32)
         vp = jax.random.normal(jax.random.fold_in(key, w + 1),
-                               (nb, bs, kh, dh), jnp.float32)
+                               (nb, kh, bs, dh), jnp.float32)
         tables = (1 + jnp.arange(b * mb, dtype=jnp.int32)).reshape(b, mb)
         lens = jnp.full((b,), w - 1, jnp.int32)     # full-depth decode
         positions = lens[:, None]
@@ -685,9 +685,9 @@ def run_autotune(small: bool = False):
     for cand in cands:
         cbs = cand["block_size"]
         mb = w // cbs
-        pools = kv.reshape(2, b * mb, cbs, kh, dh)
-        kp = jnp.concatenate([jnp.zeros((1, cbs, kh, dh)), pools[0]])
-        vp = jnp.concatenate([jnp.zeros((1, cbs, kh, dh)), pools[1]])
+        pools = kv.reshape(2, b * mb, cbs, kh, dh).swapaxes(2, 3)
+        kp = jnp.concatenate([jnp.zeros((1, kh, cbs, dh)), pools[0]])
+        vp = jnp.concatenate([jnp.zeros((1, kh, cbs, dh)), pools[1]])
         tables = (1 + jnp.arange(b * mb, dtype=jnp.int32)).reshape(b, mb)
         lens = jnp.full((b,), w - 1, jnp.int32)
         kvl = lens + 1

@@ -460,11 +460,9 @@ def choose_backend(cfg, x_codes: jax.Array, weights) -> str:
 def _under_vmap(*arrays) -> bool:
     """True when any operand is a vmap batch tracer — shard_map cannot nest
     under vmap, so the engine falls back to the plain per-call kernel (the
-    pre-mesh behaviour) there."""
-    try:
-        from jax.interpreters.batching import BatchTracer
-    except ImportError:  # pragma: no cover - future jax reorganisations
-        return False
+    pre-mesh behaviour) there. jax exports no public name for the tracer
+    class, so it comes from jax._src (pinned toolchain)."""
+    from jax._src.interpreters.batching import BatchTracer
     return any(isinstance(a, BatchTracer) for a in arrays)
 
 

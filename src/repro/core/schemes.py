@@ -139,6 +139,9 @@ def signed_correction(y_codes: jax.Array, x_codes: jax.Array,
         sum_w = jnp.sum(w_codes, axis=-2)                   # [..., M]
     if k is None:
         k = x_codes.shape[-1]
-    sum_x = jnp.sum(x_codes, axis=-1, keepdims=True)       # [..., 1]
+    # f32 accumulation: codes arrive in the activation dtype, and a bf16
+    # sum of K codes (≈ 10³) is not an integer-exact number
+    sum_x = jnp.sum(x_codes, axis=-1, keepdims=True,
+                    dtype=jnp.float32)                     # [..., 1]
     return (y_codes - w_offset * sum_x - x_zero_point * sum_w
             + w_offset * x_zero_point * k)

@@ -2,21 +2,19 @@
 
 Handles: leading-dim flattening, zero-padding of K to the macro depth and of
 M/N to block multiples (zero codes are unselected SRAM rows — bit-exact
-no-ops), backend selection (compiled TPU kernel vs interpret mode on CPU),
-and block-size tuning knobs used by the §Perf hillclimb.
+no-ops), the group-major activation layout the kernel reads, the choice
+between the compiled TPU kernel and interpret mode on CPU
+(`kernels.interpret_mode`), and the bm/bn tile knobs.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.macro import MacroConfig, Scheme, SimLevel
 
-from .cim_mvm import (cim_mvm_grouped, cim_mvm_grouped_noisy,
-                      cim_mvm_grouped_noisy_packed, cim_mvm_grouped_packed,
-                      salt_seed)
+from . import interpret_mode
+from .cim_mvm import cim_mvm_grouped, salt_seed
 
 __all__ = [
     "cim_mvm_pallas", "cim_mvm_pallas_packed", "cim_mvm_pallas_noisy",
@@ -105,38 +103,58 @@ def _pad_to(x: jax.Array, multiple: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _prep_dense(x_codes, w_codes, n_rows: int, bm: int, bn: int):
-    """Shared operand prep for the dense-weight kernels: flatten leading
-    dims, zero-pad K to the macro depth and M/N to block multiples (zero
-    codes are unselected SRAM rows — exact no-ops). Returns
-    (x2, w2, bm_eff, bn_eff, lead, m, n)."""
+def _prep(x_codes, w, n_rows: int, bm: int, bn: int, packed: bool):
+    """Operand prep shared by every entry: flatten leading dims, zero-pad K
+    to the macro depth and M/N to block multiples (zero codes are
+    unselected SRAM rows — exact no-ops), and lay x out group-major as the
+    kernel reads it: [G, M, n_rows], or [G, 2, M, n_rows/2] split into
+    even / odd reduction rows for nibble-packed w (zero bytes = two
+    unselected rows). Returns (xg, w2, bm_eff, bn_eff, lead, m, n)."""
     lead = x_codes.shape[:-1]
     k = x_codes.shape[-1]
     x2 = x_codes.reshape(-1, k)
-    m, n = x2.shape[0], w_codes.shape[-1]
-    x2 = _pad_to(_pad_to(x2, n_rows, 1), min(bm, max(m, 1)), 0)
-    w2 = _pad_to(_pad_to(w_codes, n_rows, 0), min(bn, max(n, 1)), 1)
-    bm_eff = bm if x2.shape[0] % bm == 0 else x2.shape[0]
-    bn_eff = bn if w2.shape[1] % bn == 0 else w2.shape[1]
-    return x2, w2, bm_eff, bn_eff, lead, m, n
-
-
-def _prep_packed(x_codes, w_packed, n_rows: int, bm: int, bn: int):
-    """Packed-weight twin of _prep_dense: x pads to the byte rows first,
-    w pads in nibble-pair units (zero bytes = two unselected rows)."""
-    lead = x_codes.shape[:-1]
-    k = x_codes.shape[-1]
-    k2 = w_packed.shape[0]
-    assert k in (2 * k2, 2 * k2 - 1), (x_codes.shape, w_packed.shape)
-    x2 = x_codes.reshape(-1, k)
-    m, n = x2.shape[0], w_packed.shape[1]
-    x2 = _pad_to(_pad_to(x2, 2, 1), n_rows, 1)
-    w2 = _pad_to(w_packed, n_rows // 2, 0)
+    m, n = x2.shape[0], w.shape[-1]
+    if packed:
+        assert k in (2 * w.shape[0], 2 * w.shape[0] - 1), \
+            (x_codes.shape, w.shape)
+        x2 = _pad_to(_pad_to(x2, 2, 1), n_rows, 1)
+        w2 = _pad_to(w, n_rows // 2, 0)
+    else:
+        x2 = _pad_to(x2, n_rows, 1)
+        w2 = _pad_to(w, n_rows, 0)
     x2 = _pad_to(x2, min(bm, max(m, 1)), 0)
     w2 = _pad_to(w2, min(bn, max(n, 1)), 1)
-    bm_eff = bm if x2.shape[0] % bm == 0 else x2.shape[0]
+    mp, groups = x2.shape[0], x2.shape[1] // n_rows
+    if packed:
+        xg = x2.reshape(mp, groups, n_rows // 2, 2).transpose(1, 3, 0, 2)
+    else:
+        xg = x2.reshape(mp, groups, n_rows).transpose(1, 0, 2)
+    bm_eff = bm if mp % bm == 0 else mp
     bn_eff = bn if w2.shape[1] % bn == 0 else w2.shape[1]
-    return x2, w2, bm_eff, bn_eff, lead, m, n
+    return xg, w2, bm_eff, bn_eff, lead, m, n
+
+
+def _run(x_codes, w, cfg: MacroConfig, *, packed: bool, noise_seed=None,
+         inl_seed: int = 0, bm=None, bn=None, interpret=None) -> jax.Array:
+    """Tiles, operand prep and the kernel call behind every entry point."""
+    assert cfg.scheme == Scheme.BP, "fused kernel implements BP only"
+    if packed:
+        assert cfg.n_rows % 2 == 0, "nibble packing needs an even macro depth"
+    interpret = interpret_mode(interpret)
+    bm, bn = _resolve_tiles(x_codes, w.shape[-1], cfg.n_rows, bm, bn)
+    xg, w2, bm_eff, bn_eff, lead, m, n = _prep(x_codes, w, cfg.n_rows, bm,
+                                                bn, packed)
+    kw = {}
+    seed = None
+    if noise_seed is not None:
+        from repro.core.adc import stochastic_transfer_params
+        kw = dict(stochastic_transfer_params(cfg), inl_seed=inl_seed)
+        seed = jnp.asarray(noise_seed, jnp.int32)
+    out = cim_mvm_grouped(
+        xg, w2, seed, levels=cfg.effective_adc_levels(), gain=cfg.gain,
+        full_scale=cfg.full_scale(), bm=bm_eff, bn=bn_eff,
+        interpret=interpret, **kw)
+    return out[:m, :n].reshape(*lead, n)
 
 
 def cim_mvm_pallas_packed(x_codes: jax.Array, w_packed: jax.Array,
@@ -146,18 +164,8 @@ def cim_mvm_pallas_packed(x_codes: jax.Array, w_packed: jax.Array,
     """ŷ ≈ Σ X̃ W̃ with 4-bit-packed weights. x [..., K], w_packed [K2, M]
     with K ≤ 2·K2 (K2 = ceil(K/2) nibble pairs). K, M and the leading dims
     are padded here; zero bytes are pairs of unselected SRAM rows."""
-    assert cfg.scheme == Scheme.BP
-    assert cfg.n_rows % 2 == 0, "nibble packing needs an even macro depth"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bm, bn = _resolve_tiles(x_codes, w_packed.shape[1], cfg.n_rows, bm, bn)
-    x2, w2, bm_eff, bn_eff, lead, m, n = _prep_packed(x_codes, w_packed,
-                                                      cfg.n_rows, bm, bn)
-    out = cim_mvm_grouped_packed(
-        x2, w2, n_rows=cfg.n_rows, levels=cfg.effective_adc_levels(),
-        gain=cfg.gain, full_scale=cfg.full_scale(), bm=bm_eff, bn=bn_eff,
-        interpret=interpret)
-    return out[:m, :n].reshape(*lead, n)
+    return _run(x_codes, w_packed, cfg, packed=True, bm=bm, bn=bn,
+                interpret=interpret)
 
 
 def cim_mvm_pallas(x_codes: jax.Array, w_codes: jax.Array, cfg: MacroConfig,
@@ -169,17 +177,8 @@ def cim_mvm_pallas(x_codes: jax.Array, w_codes: jax.Array, cfg: MacroConfig,
     Only the BP scheme is implemented as a fused kernel — it is the paper's
     deployed scheme; WBS/BS baselines run on the jnp path.
     """
-    assert cfg.scheme == Scheme.BP, "fused kernel implements BP only"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bm, bn = _resolve_tiles(x_codes, w_codes.shape[-1], cfg.n_rows, bm, bn)
-    x2, w2, bm_eff, bn_eff, lead, m, n = _prep_dense(x_codes, w_codes,
-                                                     cfg.n_rows, bm, bn)
-    out = cim_mvm_grouped(
-        x2, w2, n_rows=cfg.n_rows, levels=cfg.effective_adc_levels(),
-        gain=cfg.gain, full_scale=cfg.full_scale(), bm=bm_eff, bn=bn_eff,
-        interpret=interpret)
-    return out[:m, :n].reshape(*lead, n)
+    return _run(x_codes, w_codes, cfg, packed=False, bm=bm, bn=bn,
+                interpret=interpret)
 
 
 def cim_mvm_pallas_noisy(x_codes: jax.Array, w_codes: jax.Array,
@@ -192,23 +191,10 @@ def cim_mvm_pallas_noisy(x_codes: jax.Array, w_codes: jax.Array,
     QAT step without recompiling. σ/INL settings come from
     core.adc.stochastic_transfer_params, the same source adc_quantize uses,
     so the fused and jnp pipelines agree in distribution."""
-    from repro.core.adc import stochastic_transfer_params
-    assert cfg.scheme == Scheme.BP, "fused kernel implements BP only"
     assert cfg.sim_level != SimLevel.IDEAL, \
         "IDEAL transfer runs the deterministic kernel (cim_mvm_pallas)"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    st = stochastic_transfer_params(cfg)
-    bm, bn = _resolve_tiles(x_codes, w_codes.shape[-1], cfg.n_rows, bm, bn)
-    x2, w2, bm_eff, bn_eff, lead, m, n = _prep_dense(x_codes, w_codes,
-                                                     cfg.n_rows, bm, bn)
-    out = cim_mvm_grouped_noisy(
-        x2, w2, jnp.asarray(noise_seed, jnp.int32), n_rows=cfg.n_rows,
-        levels=cfg.effective_adc_levels(), gain=cfg.gain,
-        full_scale=cfg.full_scale(), sigma=st["sigma"],
-        inl_amp=st["inl_amp"], inl_seed=inl_seed, apply_inl=st["apply_inl"],
-        bm=bm_eff, bn=bn_eff, interpret=interpret)
-    return out[:m, :n].reshape(*lead, n)
+    return _run(x_codes, w_codes, cfg, packed=False, noise_seed=noise_seed,
+                inl_seed=inl_seed, bm=bm, bn=bn, interpret=interpret)
 
 
 def cim_mvm_pallas_noisy_packed(x_codes: jax.Array, w_packed: jax.Array,
@@ -220,20 +206,6 @@ def cim_mvm_pallas_noisy_packed(x_codes: jax.Array, w_packed: jax.Array,
     pure function of (seed, output coordinate, group) — independent of the
     weight container — so this is bit-identical to cim_mvm_pallas_noisy on
     the unpacked codes under the same seed."""
-    from repro.core.adc import stochastic_transfer_params
-    assert cfg.scheme == Scheme.BP
     assert cfg.sim_level != SimLevel.IDEAL
-    assert cfg.n_rows % 2 == 0, "nibble packing needs an even macro depth"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    st = stochastic_transfer_params(cfg)
-    bm, bn = _resolve_tiles(x_codes, w_packed.shape[1], cfg.n_rows, bm, bn)
-    x2, w2, bm_eff, bn_eff, lead, m, n = _prep_packed(x_codes, w_packed,
-                                                      cfg.n_rows, bm, bn)
-    out = cim_mvm_grouped_noisy_packed(
-        x2, w2, jnp.asarray(noise_seed, jnp.int32), n_rows=cfg.n_rows,
-        levels=cfg.effective_adc_levels(), gain=cfg.gain,
-        full_scale=cfg.full_scale(), sigma=st["sigma"],
-        inl_amp=st["inl_amp"], inl_seed=inl_seed, apply_inl=st["apply_inl"],
-        bm=bm_eff, bn=bn_eff, interpret=interpret)
-    return out[:m, :n].reshape(*lead, n)
+    return _run(x_codes, w_packed, cfg, packed=True, noise_seed=noise_seed,
+                inl_seed=inl_seed, bm=bm, bn=bn, interpret=interpret)

@@ -37,7 +37,10 @@ Kernel layout (grid = (B, KH, MB), MB = blocks per slot window):
     as scalar-prefetch operands: the K/V BlockSpec index maps read
     `tables[b, j]`, so the pool block each grid step DMAs into VMEM IS the
     slot's j-th logical block — a gather the kernel gets for free from the
-    pipeline, with no [B, W, KH, dh] windowed copy ever materialized;
+    pipeline, with no [B, W, KH, dh] windowed copy ever materialized. The
+    pools are head-major inside a block ([NB, KH, bs, dh]), so one fetch is
+    a (1, 1, bs, dh) block whose last two dims equal the pool's — the
+    block shape Mosaic accepts for any bs and dh;
   * GQA is folded as rows: q arrives [B, KH, C·G, dh] (C = chunk width, G
     = query heads per KV head), so decode (C=1) and chunked prefill are
     the SAME kernel — the causal mask per row uses that row's chunk
@@ -73,14 +76,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
+from repro.kernels import interpret_mode
 from repro.parallel import sharding
 from repro.runtime.telemetry import KERNEL_COUNTERS
-
-# jax renamed TPUCompilerParams → CompilerParams across 0.4.x/0.5.x (same
-# shim as kernels/cim_mvm.py) — support both toolchains.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 # ---------------------------------------------------------------------------
 # backend registry (mirrors core.engine's CIM backend registry)
@@ -90,7 +88,7 @@ class AttnBackendSpec:
     """One paged-attention evaluation strategy.
 
     fn(q, k_pool, v_pool, tables, positions, kv_len) -> o
-      q [B, C, H, dh] (C = 1 for decode); pools [NB, bs, KH, dh];
+      q [B, C, H, dh] (C = 1 for decode); pools [NB, KH, bs, dh];
       tables [B, MB] physical block ids; positions [B, C] absolute query
       positions (= lens + chunk offset); kv_len [B] tokens valid in the
       window INCLUDING this step's writes. Returns [B, C, H, dh].
@@ -182,7 +180,7 @@ def _paged_attn_kernel(tables_ref, lens_ref, kvl_ref, q_ref, *refs,
     the MB blocks, `kblocks` logical blocks per step.
 
     q_ref [1, 1, RT, dh] (RT = row tile of the C·G query rows); the step's
-    KV arrives as `kblocks` separate [1, bs, 1, dh] refs — the slot's
+    KV arrives as `kblocks` separate [1, 1, bs, dh] refs — the slot's
     logical blocks j·kblocks … j·kblocks+kblocks−1, each fetched by its own
     index map through the scalar-prefetched table, so the pipeline double-
     buffers a [kblocks·bs, dh] span per sequential step. Scratch holds the
@@ -215,9 +213,9 @@ def _paged_attn_kernel(tables_ref, lens_ref, kvl_ref, q_ref, *refs,
     def _block():
         q = q_ref[0, 0].astype(jnp.float32)            # [RT, dh]
         k = jnp.concatenate(                           # [span, dh]
-            [kr[0, :, 0, :] for kr in k_refs], axis=0).astype(jnp.float32)
+            [kr[0, 0] for kr in k_refs], axis=0).astype(jnp.float32)
         v = jnp.concatenate(
-            [vr[0, :, 0, :] for vr in v_refs], axis=0).astype(jnp.float32)
+            [vr[0, 0] for vr in v_refs], axis=0).astype(jnp.float32)
         rt = q.shape[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -238,7 +236,9 @@ def _paged_attn_kernel(tables_ref, lens_ref, kvl_ref, q_ref, *refs,
         alpha = jnp.exp(m_prev - m_new)
         # zero invalid V rows pre-dot via where (0-weight · NaN-garbage is
         # still NaN, and so is 0 · NaN from a mask multiply)
-        v = jnp.where(pos_s[0:1, :].T < kvl, v, 0.0)
+        pos_v = j * span \
+            + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        v = jnp.where(pos_v < kvl, v, 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha \
             + jnp.dot(p, v, preferred_element_type=jnp.float32)
@@ -276,9 +276,9 @@ def _paged_attn_call(q3, k_pool, v_pool, tables, lens, kvl, *,
         # i-th sub-block of the step's kblocks-wide span; default-arg bind
         # so each spec closes over its own stride offset
         return lambda b, h, r, j, t, ln, kv, i=i: (t[b, j * kblocks + i],
-                                                   0, h, 0)
+                                                   h, 0, 0)
 
-    kv_spec = [pl.BlockSpec((1, block_size, 1, dh), _kv_map(i))
+    kv_spec = [pl.BlockSpec((1, 1, block_size, dh), _kv_map(i))
                for i in range(kblocks)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -301,7 +301,7 @@ def _paged_attn_call(q3, k_pool, v_pool, tables, lens, kvl, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, cg, dh), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -339,7 +339,7 @@ def paged_flash_attention(q, k_pool, v_pool, tables, lens, kv_len, *,
                           interpret: bool | None = None,
                           kblocks: int | None = None,
                           row_tile: int | None = None):
-    """Flash-style paged attention: q [B, C, H, dh] × pools [NB, bs, KH, dh]
+    """Flash-style paged attention: q [B, C, H, dh] × pools [NB, KH, bs, dh]
     through per-slot block tables [B, MB] → [B, C, H, dh].
 
     lens [B] = tokens already cached per slot BEFORE this step's writes
@@ -354,12 +354,10 @@ def paged_flash_attention(q, k_pool, v_pool, tables, lens, kv_len, *,
     the C·G query rows split into `row_tile`-high parallel tiles (rows are
     padded with dummy queries to divide — their outputs are sliced away).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     b, c, h, dh = q.shape
-    kh = k_pool.shape[2]
+    kh, bs = k_pool.shape[1:3]
     g = h // kh
-    bs = k_pool.shape[1]
     mb = tables.shape[1]
     cg = c * g
     if kblocks is None and row_tile is None:
@@ -410,7 +408,7 @@ def _fused_write_kernel(wblk_ref, woff_ref, wval_ref, nk_ref, nv_ref,
     b = pl.program_id(0)
     off = woff_ref[b]
     valid = wval_ref[b]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (1, block_size, 1, 1), 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size, 1), 2)
     sel = (rows == off) & (valid != 0)
     ko_ref[...] = jnp.where(sel, nk_ref[...], k_ref[...])
     vo_ref[...] = jnp.where(sel, nv_ref[...], v_ref[...])
@@ -419,12 +417,12 @@ def _fused_write_kernel(wblk_ref, woff_ref, wval_ref, nk_ref, nv_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fused_write_call(k_pool, v_pool, new_k, new_v, wblk, woff, wval, *,
                       interpret: bool):
-    nb, bs, kh, dh = k_pool.shape
+    nb, kh, bs, dh = k_pool.shape
     b = new_k.shape[0]
     kern = functools.partial(_fused_write_kernel, block_size=bs)
-    new_spec = pl.BlockSpec((1, 1, kh, dh),
+    new_spec = pl.BlockSpec((1, kh, 1, dh),
                             lambda b, t, o, v: (b, 0, 0, 0))
-    pool_spec = pl.BlockSpec((1, bs, kh, dh),
+    pool_spec = pl.BlockSpec((1, kh, bs, dh),
                              lambda b, t, o, v: (t[b], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -440,12 +438,14 @@ def _fused_write_call(k_pool, v_pool, new_k, new_v, wblk, woff, wval, *,
         # pools alias their outputs (operand indices count the 3 scalar-
         # prefetch refs): blocks no grid step visits keep their bytes
         input_output_aliases={5: 0, 6: 1},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(wblk.astype(jnp.int32), woff.astype(jnp.int32),
-      wval.astype(jnp.int32), new_k.astype(k_pool.dtype),
-      new_v.astype(v_pool.dtype), k_pool, v_pool)
+      wval.astype(jnp.int32),
+      # [B, 1, KH, dh] → [B, KH, 1, dh]: the same bytes (C = 1)
+      new_k.reshape(b, kh, 1, dh).astype(k_pool.dtype),
+      new_v.reshape(b, kh, 1, dh).astype(v_pool.dtype), k_pool, v_pool)
 
 
 def fused_paged_write(k_pool, v_pool, new_k, new_v, flat_idx, *,
@@ -466,9 +466,8 @@ def fused_paged_write(k_pool, v_pool, new_k, new_v, flat_idx, *,
     the kernel never needs to know about refcounts, and must never be
     handed a table whose write-span blocks are still shared.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bs = k_pool.shape[1]
+    interpret = interpret_mode(interpret)
+    bs = k_pool.shape[2]
     fi = flat_idx.reshape(-1).astype(jnp.int32)
     return _fused_write_call(k_pool, v_pool, new_k, new_v,
                              fi // bs, fi % bs, (fi != 0).astype(jnp.int32),
@@ -490,7 +489,7 @@ def _mesh_attn_specs(mesh, kh: int):
             and kh % mesh.shape["model"] == 0:
         heads = "model"
     q_spec = PartitionSpec(None, heads, None, None)
-    pool_spec = PartitionSpec(None, None, heads, None)
+    pool_spec = PartitionSpec(None, heads, None, None)
     return q_spec, pool_spec
 
 
@@ -498,7 +497,7 @@ def paged_attention(q, k_pool, v_pool, tables, *, positions, kv_len,
                     backend: str = "auto"):
     """Attend q over a paged KV pool through per-slot block tables.
 
-    q [B, C, H, dh]; pools [NB, bs, KH, dh]; tables [B, MB]; positions
+    q [B, C, H, dh]; pools [NB, KH, bs, dh]; tables [B, MB]; positions
     [B, C] absolute query positions (lens + chunk offset, as built by
     transformer.paged_step); kv_len [B]. Returns [B, C, H, dh]. `backend`
     is "auto" | "exact" | "kernel" (see module docstring; models thread
@@ -517,7 +516,7 @@ def paged_attention(q, k_pool, v_pool, tables, *, positions, kv_len,
         return spec.fn(q, k_pool, v_pool, tables, positions, kv_len)
 
     b, c, h, dh = q.shape
-    kh = k_pool.shape[2]
+    kh = k_pool.shape[1]
     q5 = q.reshape(b, c, kh, h // kh, dh)   # split heads → KH is an axis
     q_spec, pool_spec = _mesh_attn_specs(mesh, kh)
     q5_spec = PartitionSpec(None, None, q_spec[1], None, None)

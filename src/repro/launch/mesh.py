@@ -10,13 +10,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: AxisType.Auto landed in 0.5.x;
-    older toolchains take no axis_types argument (same Auto semantics)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-partitioned), the mode the
+    repo's sharding rules are written for."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

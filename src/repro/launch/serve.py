@@ -8,7 +8,7 @@
       --paged --prefill-chunk 8 --block-size 16 [--cim bp-prequant]
 
   # Pallas paged-attention kernel (block gather + online softmax in VMEM;
-  # interpret mode off-TPU) + static calibrated input-DAC scales
+  # interpret mode on CPU) + static calibrated input-DAC scales
   PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b --smoke \
       --paged --attn kernel [--cim bp --act-scale static]
 
@@ -75,13 +75,15 @@ import numpy as np
 
 from repro.configs.registry import ARCHS, SMOKES
 from repro.core.cim_matmul import CIMConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.parallel import sharding
 from repro.runtime.server import Request, Server, ServingConfig
 from repro.runtime.speculative import SamplingParams
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serving flags (the namespace ServingConfig.from_flags maps)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS),
                     default="internlm2-1.8b",
@@ -167,7 +169,7 @@ def main():
                          "registry): exact = window gather + one-pass "
                          "softmax (the [B,C,KH,G,W]-score reference), "
                          "kernel = Pallas flash decode/prefill over the "
-                         "block tables (interpret mode off-TPU), auto = "
+                         "block tables (interpret mode on CPU), auto = "
                          "kernel unless REPRO_FORCE_JNP=1 pins exact")
     ap.add_argument("--act-scale", choices=("dynamic", "static"),
                     default="dynamic",
@@ -187,7 +189,7 @@ def main():
                     default="off",
                     help="bp-noisy = NOISY converter chain with "
                          "noise_seed=0; backend=auto resolves to the fused "
-                         "stochastic Pallas kernel (interpret mode off-TPU) "
+                         "stochastic Pallas kernel (interpret mode on CPU) "
                          "— on a mesh (--mesh host) the engine wraps it in "
                          "shard_map, so sharded serving no longer falls "
                          "back to the jnp scan backend")
@@ -221,8 +223,42 @@ def main():
                          "REPRO_SERVE_DEVICES=N for N placeholder CPU "
                          "devices) — executes the mesh-sharded CIM engine "
                          "end-to-end")
-    args = ap.parse_args()
+    return ap
 
+
+def install_mesh(args):
+    """`--mesh host`: install a data×model mesh over the available devices
+    as the process-wide serving mesh. Returns it (None for --mesh none)."""
+    if args.mesh != "host":
+        return None
+    from repro.launch.mesh import make_host_smoke_mesh
+    mesh, data, model = make_host_smoke_mesh()
+    sharding.set_mesh(mesh)
+    print(f"serving on host mesh data={data} model={model}")
+    return mesh
+
+
+def model_config(args):
+    """The model config the flags select: smoke or full geometry, with the
+    --cim preset applied."""
+    cfg = (SMOKES if args.smoke else ARCHS)[args.arch]
+    if args.cim == "bp-noisy":
+        import dataclasses
+        from repro.core.macro import SimLevel
+        cim = CIMConfig(enabled=True, noise_seed=0)
+        cfg = cfg.replace(cim=dataclasses.replace(
+            cim, macro=dataclasses.replace(cim.macro,
+                                           sim_level=SimLevel.NOISY)))
+    elif args.cim != "off":
+        cfg = cfg.replace(cim=CIMConfig(enabled=True))
+    return cfg
+
+
+def build_server(args, params=None) -> Server:
+    """The Server the flags describe. `params` (from registry.init_params
+    for this architecture) are initialised from seed 0 when not given; a
+    caller serving several modes of one model passes them to share one
+    copy. Flag combinations are validated by main()."""
     if args.tune_cache:
         os.environ["REPRO_TUNE_CACHE"] = args.tune_cache
     if args.block_size is None:
@@ -237,33 +273,12 @@ def main():
                 args.block_size = tuned["block_size"]
                 print(f"tuned paged-pool block_size={args.block_size} "
                       f"(from {args.tune_cache})")
-
-    mesh_ctx = contextlib.nullcontext()
-    if args.mesh == "host":
-        from repro.launch.mesh import make_host_smoke_mesh
-        mesh, data, model = make_host_smoke_mesh()
-        sharding.set_mesh(mesh)
-        mesh_ctx = mesh
-        print(f"serving on host mesh data={data} model={model}")
-
-    cfg = (SMOKES if args.smoke else ARCHS)[args.arch]
-    if args.cim == "bp-noisy":
-        import dataclasses
-        from repro.core.macro import SimLevel
-        cim = CIMConfig(enabled=True, noise_seed=0)
-        cfg = cfg.replace(cim=dataclasses.replace(
-            cim, macro=dataclasses.replace(cim.macro,
-                                           sim_level=SimLevel.NOISY)))
-    elif args.cim != "off":
-        cfg = cfg.replace(cim=CIMConfig(enabled=True))
-    params = registry.init_params(jax.random.PRNGKey(0), cfg,
-                                  max_seq=args.max_len)
-    if args.precision_manifest and args.cim == "off":
-        ap.error("--precision-manifest needs a --cim mode")
+    cfg = model_config(args)
+    if params is None:
+        params = registry.init_params(jax.random.PRNGKey(0), cfg,
+                                      max_seq=args.max_len)
     act_scale = act_zero_point = None
     if args.act_scale == "static":
-        if args.cim == "off":
-            ap.error("--act-scale static needs a --cim mode")
         from repro.analysis.calibrate import calibrate_act_scale
         cal_rng = np.random.RandomState(7)
         cal_tokens = cal_rng.randint(0, cfg.vocab, size=(2, 16))
@@ -276,19 +291,37 @@ def main():
               f"matmul sites)")
     serving = ServingConfig.from_flags(args, act_scale=act_scale,
                                        act_zero_point=act_zero_point)
-    server = Server(params, cfg, serving)
+    return Server(params, cfg, serving)
 
+
+def synthetic_requests(args, vocab: int) -> list[Request]:
+    """The seeded synthetic workload: prompts of 4–16 random tokens."""
     rng = np.random.RandomState(0)
     reqs = []
     for i in range(args.requests):
         plen = int(rng.randint(4, 17))
-        prompt = rng.randint(0, cfg.vocab, size=plen).tolist()
+        prompt = rng.randint(0, vocab, size=plen).tolist()
         reqs.append(Request(prompt=prompt, max_new_tokens=args.max_new,
                             n_samples=args.n_samples,
                             sampling=SamplingParams(
                                 temperature=args.temperature,
                                 top_k=args.top_k,
                                 seed=args.sample_seed + i)))
+    return reqs
+
+
+def main():
+    ap = build_parser()
+    args = ap.parse_args()
+    if args.precision_manifest and args.cim == "off":
+        ap.error("--precision-manifest needs a --cim mode")
+    if args.act_scale == "static" and args.cim == "off":
+        ap.error("--act-scale static needs a --cim mode")
+    enable_compile_cache()
+    mesh = install_mesh(args)
+    mesh_ctx = mesh if mesh is not None else contextlib.nullcontext()
+    server = build_server(args)
+    reqs = synthetic_requests(args, server.cfg.vocab)
     due = None
     if args.arrival == "poisson":
         arr_rng = np.random.RandomState(args.arrival_seed)

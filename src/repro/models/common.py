@@ -330,23 +330,22 @@ def paged_write(pool: jax.Array, new: jax.Array,
                 flat_idx: jax.Array) -> jax.Array:
     """Scatter per-token K or V rows into a block pool.
 
-    pool [NB, bs, KH, dh]; new [B, C, KH, dh]; flat_idx [B, C] indexes the
-    flattened (NB·bs) token-slot axis. Masked lanes arrive pre-pointed at
-    the trash block (flat index 0..bs-1) by the caller, so no separate mask
-    is needed here — duplicate trash writes land in storage that is never
-    read with non-zero attention weight.
+    pool [NB, KH, bs, dh]; new [B, C, KH, dh]; flat_idx [B, C] indexes the
+    flattened (NB·bs) token-slot space (block · bs + offset). Masked lanes
+    arrive pre-pointed at the trash block (flat index 0..bs-1) by the
+    caller, so no separate mask is needed here — duplicate trash writes
+    land in storage that is never read with non-zero attention weight.
     """
-    nb, bs = pool.shape[:2]
-    flat = pool.reshape(nb * bs, *pool.shape[2:])
-    flat = flat.at[flat_idx.reshape(-1)].set(
+    bs = pool.shape[2]
+    fi = flat_idx.reshape(-1)
+    return pool.at[fi // bs, :, fi % bs].set(
         new.reshape(-1, *new.shape[2:]).astype(pool.dtype))
-    return flat.reshape(pool.shape)
 
 
 def paged_gather(pool: jax.Array, tables: jax.Array) -> jax.Array:
     """Gather each slot's window from the block pool.
 
-    pool [NB, bs, KH, dh]; tables [B, MB] physical block ids. Returns the
+    pool [NB, KH, bs, dh]; tables [B, MB] physical block ids. Returns the
     contiguous per-slot view [B, MB·bs, KH, dh] — the same window shape the
     dense slot cache gave decode_attention, so the per-position math (and,
     for decode, the bits) match the unpaged path. Unallocated table entries
@@ -354,8 +353,9 @@ def paged_gather(pool: jax.Array, tables: jax.Array) -> jax.Array:
     and are masked before any softmax.
     """
     b, mb = tables.shape
-    win = pool[tables]                       # [B, MB, bs, KH, dh]
-    return win.reshape(b, mb * pool.shape[1], *pool.shape[2:])
+    _, kh, bs, dh = pool.shape
+    win = pool[tables].swapaxes(2, 3)        # [B, MB, bs, KH, dh]
+    return win.reshape(b, mb * bs, kh, dh)
 
 
 def paged_prefill_attention(q: jax.Array, k_win: jax.Array, v_win: jax.Array,
@@ -400,7 +400,7 @@ def paged_attention_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
     """Self-attention over a paged KV pool — the unified prefill/decode step.
 
     x [B, C, D] (C = 1 for decode, the prefill chunk width otherwise);
-    cache {"k": [NB, bs, KH, dh], "v": ...} is ONE layer's physical pool.
+    cache {"k": [NB, KH, bs, dh], "v": ...} is ONE layer's physical pool.
     Projects and RoPEs this step's tokens at their true per-slot positions,
     scatters them into the pool at flat_idx (masked lanes → trash block),
     and attends with per-slot lengths through the attention-backend

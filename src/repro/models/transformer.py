@@ -425,9 +425,11 @@ def supports_paged(cfg: ModelConfig) -> bool:
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int,
                      block_size: int) -> dict:
-    """Physical KV block pools [L, NB, bs, KH, dh] (zeros).
+    """Physical KV block pools [L, NB, KH, bs, dh] (zeros).
 
     One pool per layer stack; NB includes the trash block (physical id 0).
+    Head-major inside a block: one (block, head) tile is [bs, dh], which is
+    the TPU-legal KV fetch of the paged-attention kernel.
     Unlike init_cache there is no per-slot batch axis — slots share the pool
     through their block tables, so resident bytes scale with allocated
     blocks, not n_slots × max_len.
@@ -439,7 +441,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
     dt = dtype_of(cfg)
     n_wide = cfg.moe.first_dense if cfg.moe else 0
     n_main = cfg.n_layers - n_wide
-    kvd = (num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    kvd = (num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     mk = lambda L: {"k": jnp.zeros((L,) + kvd, dt),
                     "v": jnp.zeros((L,) + kvd, dt)}
     cache = {"layers": mk(n_main)}
@@ -457,7 +459,7 @@ def cow_copy_block(cache: dict, src: jax.Array, dst: jax.Array) -> dict:
     here, then remaps the lane's table. src/dst are traced int32 scalars
     so every fork shares one compilation; the server jits this with the
     cache donated, making it an in-place device copy. Pools are
-    [L, NB, bs, KH, dh], so the block axis is axis 1 on every leaf.
+    [L, NB, KH, bs, dh], so the block axis is axis 1 on every leaf.
     """
     return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), cache)
 
@@ -514,7 +516,7 @@ def paged_step(params: dict, tokens: jax.Array, cache: dict,
     their final chunk).
     """
     b, c = tokens.shape
-    block_size = jax.tree_util.tree_leaves(cache)[0].shape[2]
+    block_size = jax.tree_util.tree_leaves(cache)[0].shape[3]
     window = tables.shape[1] * block_size
     positions = lens[:, None] + jnp.arange(c)[None, :]          # [B, C]
 

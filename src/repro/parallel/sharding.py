@@ -53,13 +53,10 @@ def in_shard_context() -> bool:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map across jax versions.
+    """jax.shard_map that marks its body trace.
 
-    Newer jax exposes it at the top level with a `check_vma` flag; 0.4.x
-    has jax.experimental.shard_map.shard_map with the same semantics under
-    `check_rep`. All repo call sites go through this wrapper, which also
-    marks the body trace so `in_shard_context()` reports per-shard
-    execution (the CIM engine's nesting guard).
+    All repo call sites go through this wrapper, so `in_shard_context()`
+    reports per-shard execution (the CIM engine's nesting guard).
     """
     @functools.wraps(f)
     def body(*args, **kwargs):
@@ -69,13 +66,8 @@ def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
         finally:
             _SHARD_DEPTH[0] -= 1
 
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

@@ -128,6 +128,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import registry
+from repro.parallel import sharding
 from repro.runtime.paging import BlockAllocator, PrefixTrie, SlotTables
 from repro.runtime.speculative import SamplingParams, make_drafter, \
     parse_drafter, sample_token, verify_token
@@ -385,6 +386,10 @@ class Server:
             assert cfg.cim.enabled, "prequant serving needs cim.enabled"
             from repro.models.quantize import quantize_params
             params = quantize_params(params, cfg, packed=serving.packed)
+        if sharding.get_mesh() is not None:
+            # freshly initialised parameters all sit on the first device;
+            # lay them out by the PARAM_RULES so each device holds its shard
+            params = jax.device_put(params, sharding.tree_shardings(params))
         self.params = params
         self.cfg = cfg
         self.n_slots = serving.n_slots
@@ -419,9 +424,18 @@ class Server:
             self._watermark = max(1, round(num_blocks * serving.watermark)) \
                 if serving.watermark > 0 else 0
             # pool holds num_blocks usable blocks + the trash block (id 0)
-            self.cache = jax.jit(
-                lambda: self.mod.init_paged_cache(cfg, num_blocks + 1,
-                                                  self.block_size))()
+            def init_pool():
+                return self.mod.init_paged_cache(cfg, num_blocks + 1,
+                                                 self.block_size)
+            pool_shardings = None
+            if sharding.get_mesh() is not None:
+                # pools [L, NB, KH, bs, dh]: KV heads over "model", the
+                # layout the paged-attention mesh dispatch reads
+                pool_shardings = jax.tree.map(
+                    lambda a: sharding.sharding_for(
+                        a.shape, (None, None, "tp", None, None)),
+                    jax.eval_shape(init_pool))
+            self.cache = jax.jit(init_pool, out_shardings=pool_shardings)()
             self._pstep = jax.jit(
                 lambda p, t, c, tb, ln, vd:
                     self.mod.paged_step(p, t, c, tb, ln, vd, cfg))

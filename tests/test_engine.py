@@ -606,3 +606,22 @@ def test_moe_expert_weights_respect_cim_switch():
     p_int8 = {"e_gate_q": jnp.zeros((4, 8, 8), jnp.int8),
               "e_gate_scale": jnp.ones((4, 1, 1))}
     assert set(_expert_weights(p_int8, "e_gate", cfg_on)) == {"q", "s"}
+
+
+def test_under_vmap_detects_batch_tracers():
+    """The mesh dispatch's vmap guard (shard_map cannot nest under vmap)
+    sees vmap's batch tracers — and nothing else: not concrete arrays,
+    not plain jit tracers."""
+    from repro.core.engine import _under_vmap
+    seen = []
+
+    def probe(a):
+        seen.append(_under_vmap(a))
+        return a
+
+    jax.vmap(probe)(jnp.ones((2, 3)))
+    jax.jit(jax.vmap(probe))(jnp.ones((2, 3)))
+    assert seen == [True, True]
+    jax.jit(probe)(jnp.ones(3))
+    assert seen[-1] is False
+    assert not _under_vmap(jnp.ones(3))

@@ -1,8 +1,9 @@
 """Pallas cim_mvm kernel vs pure-jnp oracle: shape/dtype sweeps + properties.
 
-interpret=True executes the kernel body on CPU (the brief's validation mode);
-tolerance is a couple of float32 ULPs of the LSB-scaled accumulation (the
-kernel and oracle may sum groups in different orders).
+interpret=True executes the kernel body on CPU. The kernel and the oracle
+both accumulate integer ADC codes (exact in f32 in any order) and scale by
+the LSB once, so they agree bit-for-bit; comparisons against the core jnp
+pipeline keep a float tolerance (it sums LSB-scaled partials).
 
 The whole module calls the Pallas kernels directly, so it is skipped under
 REPRO_FORCE_JNP=1 — that CI leg models an environment WITHOUT interpret-mode
@@ -23,7 +24,7 @@ pytestmark = pytest.mark.skipif(
     in ("1", "true", "yes"),
     reason="direct Pallas kernel tests; REPRO_FORCE_JNP leg is jnp-only")
 
-from repro.core.macro import MacroConfig
+from repro.core.macro import MacroConfig, SimLevel
 from repro.core.schemes import bp_mvm
 from repro.kernels.ops import cim_mvm_pallas
 from repro.kernels.ref import cim_mvm_ref
@@ -49,8 +50,8 @@ def test_kernel_matches_ref_shapes(m, k, n):
         jnp.pad(w, ((0, kp - k), (0, 0)))
     y_r = cim_mvm_ref(xp, wp, n_rows=cfg.n_rows, levels=cfg.adc_levels,
                       gain=cfg.gain, full_scale=cfg.full_scale())
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
-                               rtol=1e-6, atol=1e-1)
+    # integer code accumulation on both sides: bit-identical
+    np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
@@ -148,3 +149,44 @@ def test_pack_codes_roundtrip():
     lo, hi = p & 15, (p >> 4) & 15
     recon = np.stack([lo, hi], 1).reshape(10, 7)
     np.testing.assert_array_equal(recon, np.asarray(w))
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """The kernels interpret on the CPU backend and compile on the TPU; any
+    other backend raises instead of silently interpreting — at every
+    kernel entry point, before anything is traced."""
+    from repro.kernels import interpret_mode
+    from repro.kernels.ops import (cim_mvm_pallas_noisy,
+                                   cim_mvm_pallas_noisy_packed,
+                                   cim_mvm_pallas_packed, pack_codes)
+    from repro.kernels.paged_attention import (fused_paged_write,
+                                               paged_flash_attention)
+    cfg = MacroConfig()
+    noisy = dataclasses.replace(cfg, sim_level=SimLevel.NOISY)
+    x = _codes(jax.random.PRNGKey(31), (4, 144))
+    w = _codes(jax.random.PRNGKey(32), (144, 8))
+    pool = jnp.zeros((3, 1, 4, 8))
+    new = jnp.zeros((1, 1, 1, 8))
+    tables = jnp.ones((1, 2), jnp.int32)
+    lens = jnp.zeros((1,), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert interpret_mode(True) is True      # an explicit choice wins
+    calls = [
+        lambda: interpret_mode(),
+        lambda: cim_mvm_pallas(x, w, cfg),
+        lambda: cim_mvm_pallas_packed(x, pack_codes(w), cfg),
+        lambda: cim_mvm_pallas_noisy(x, w, noisy, noise_seed=1),
+        lambda: cim_mvm_pallas_noisy_packed(x, pack_codes(w), noisy,
+                                            noise_seed=1),
+        lambda: paged_flash_attention(jnp.zeros((1, 1, 1, 8)), pool, pool,
+                                      tables, lens, lens + 1),
+        lambda: fused_paged_write(pool, pool, new, new,
+                                  jnp.zeros((1, 1), jnp.int32)),
+    ]
+    for call in calls:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            call()

@@ -39,9 +39,9 @@ def _make_case(seed, *, b=3, kh=2, g=2, dh=32, bs=8, mb=5, c=1,
     w = mb * bs
     nb = b * mb + 1
     q = jax.random.normal(key, (b, c, kh * g, dh), jnp.float32)
-    kp = jax.random.normal(jax.random.fold_in(key, 1), (nb, bs, kh, dh),
+    kp = jax.random.normal(jax.random.fold_in(key, 1), (nb, kh, bs, dh),
                            jnp.float32)
-    vp = jax.random.normal(jax.random.fold_in(key, 2), (nb, bs, kh, dh),
+    vp = jax.random.normal(jax.random.fold_in(key, 2), (nb, kh, bs, dh),
                            jnp.float32)
     if full_depth:
         lens = np.full(b, w - c, np.int64)
@@ -173,7 +173,7 @@ def test_stale_block_tail_poison_invariance():
     at or past each slot's kv_len and require bit-identical outputs."""
     case = _make_case(37, b=2, mb=4, c=3)
     q, kp, vp, tables, positions, kvl = case
-    bs = kp.shape[1]
+    bs = kp.shape[2]
     kp_p, vp_p = np.asarray(kp).copy(), np.asarray(vp).copy()
     for s in range(tables.shape[0]):
         for j, blk in enumerate(np.asarray(tables[s])):
@@ -181,8 +181,8 @@ def test_stale_block_tail_poison_invariance():
                 continue
             off = int(kvl[s]) - j * bs
             if off < bs:
-                kp_p[blk, max(off, 0):] = np.nan
-                vp_p[blk, max(off, 0):] = np.nan
+                kp_p[blk, :, max(off, 0):] = np.nan
+                vp_p[blk, :, max(off, 0):] = np.nan
     for backend in ("exact", "kernel"):
         clean = pa.paged_attention(q, kp, vp, tables, positions=positions,
                                    kv_len=kvl, backend=backend)
@@ -262,8 +262,7 @@ def test_fused_write_bit_identity():
     from repro.models import common
     case = _make_case(71, b=3, mb=5, c=1)
     q, kp, vp, tables, positions, kvl = case
-    b, kh, dh = q.shape[0], kp.shape[2], kp.shape[3]
-    bs = kp.shape[1]
+    b, kh, bs, dh = q.shape[0], *kp.shape[1:]
     key = jax.random.PRNGKey(91)
     new_k = jax.random.normal(key, (b, 1, kh, dh), jnp.float32)
     new_v = jax.random.normal(jax.random.fold_in(key, 1), (b, 1, kh, dh),

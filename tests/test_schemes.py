@@ -41,6 +41,23 @@ def test_serial_schemes_bit_exact_at_full_resolution(fn, scheme):
     assert jnp.array_equal(fn(x, w, cfg), exact_mvm_codes(x, w))
 
 
+def test_signed_correction_sums_bf16_codes_exactly():
+    """Activation codes arrive in the model's dtype (bf16 when serving);
+    their sum over K = 2048 exceeds bf16's 8-bit mantissa, so the
+    correction must accumulate it in f32 to stay integer-exact."""
+    k = 2048
+    x_codes = jax.random.randint(jax.random.PRNGKey(3), (4, k), 0, 16)
+    w_codes = jax.random.randint(jax.random.PRNGKey(4), (k, 8), 0, 16)
+    y = jnp.zeros((4, 8), jnp.float32)
+    kw = dict(w_offset=8, x_zero_point=jnp.float32(3))
+    exact = signed_correction(y, x_codes.astype(jnp.float32),
+                              w_codes.astype(jnp.float32), **kw)
+    served = signed_correction(y, x_codes.astype(jnp.bfloat16),
+                               w_codes.astype(jnp.float32), **kw)
+    assert served.dtype == jnp.float32
+    assert bool(jnp.array_equal(served, exact))
+
+
 def test_signed_correction_is_exact_integer_identity():
     """Eq. 7 (generalized): the offset/zero-point correction is exact."""
     key = jax.random.PRNGKey(4)

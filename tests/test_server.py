@@ -89,3 +89,27 @@ def test_prequant_packed_serving_matches_unpacked():
         assert req.done
         outs[packed] = req.output
     assert outs[True] == outs[False]
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """serve.py's compile cache: JAX_COMPILATION_CACHE_DIR when it is set
+    (left to JAX, no other dir set in code), else the fixed, gitignored
+    `<repo>/.jax_cache`."""
+    import os
+
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == compile_cache.REPO_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    root, name = os.path.split(path)
+    assert name == ".jax_cache"
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -150,13 +150,16 @@ def test_soak_waves_vs_legacy_and_single(setup):
 def test_eos_retirement_paged(setup):
     cfg, params, one_at_a_time = setup
     ref = one_at_a_time([1, 2, 3], 8)
-    eos = ref[2]
+    # the eos id must not occur earlier in the stream, or the request would
+    # (rightly) retire at that earlier occurrence
+    cut = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
+    eos = ref[cut]
     server = _mk_server(cfg, params, n_slots=1)
     req = Request(prompt=[1, 2, 3], max_new_tokens=8, eos_id=eos)
     server.submit(req)
     server.run_until_drained()
-    assert req.done and len(req.output) == 3
-    assert req.output == ref[:3]
+    assert req.done and len(req.output) == cut + 1
+    assert req.output == ref[:cut + 1]
 
 
 # ---------------------------------------------------------------------------
@@ -461,3 +464,70 @@ def test_unsupported_arch_raises():
     with pytest.raises(NotImplementedError):
         Server(params, cfg, ServingConfig(n_slots=1, max_len=32, paged=True,
                                           block_size=8))
+
+
+# ---------------------------------------------------------------------------
+# mesh-sharded serving (subprocess: 2 forced host devices, `--mesh host`)
+# ---------------------------------------------------------------------------
+MESH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.pop("REPRO_FORCE_JNP", None)
+import jax
+from repro.launch import serve
+from repro.models import registry
+from repro.parallel import sharding
+
+flags = ["--smoke", "--paged", "--attn", "kernel", "--cim", "bp-prequant",
+         "--slots", "4", "--requests", "4", "--max-new", "6",
+         "--max-len", "64"]
+args = serve.build_parser().parse_args(flags)
+params = registry.init_params(jax.random.PRNGKey(0), serve.model_config(args),
+                              max_seq=args.max_len)
+streams = {}
+for mesh_flag in ("none", "host"):
+    args = serve.build_parser().parse_args(flags + ["--mesh", mesh_flag])
+    mesh = serve.install_mesh(args)
+    try:
+        server = serve.build_server(args, params=params)
+        reqs = serve.synthetic_requests(args, server.cfg.vocab)
+        for r in reqs:
+            server.submit(r)
+        server.run_until_drained()
+    finally:
+        sharding.set_mesh(None)
+    streams[mesh_flag] = [r.output for r in reqs]
+    assert all(r.done and len(r.output) == 6 for r in reqs)
+    leaves = jax.tree_util.tree_leaves(server.params)
+    split = sum(not a.sharding.is_fully_replicated for a in leaves)
+    pools = jax.tree_util.tree_leaves(server.cache)
+    if mesh_flag == "host":
+        # the parameters and the KV pools (KV heads over "model") are laid
+        # out over the mesh, not left on the device they started on
+        assert {d for a in leaves for d in a.sharding.device_set} \
+            == set(jax.devices())
+        assert split > 0
+        assert all(len(p.sharding.device_set) == 2
+                   and not p.sharding.is_fully_replicated for p in pools)
+    else:
+        assert split == 0
+assert streams["none"] == streams["host"], streams
+print("MESH_SERVING_OK")
+"""
+
+
+def test_mesh_sharded_serving_matches_unsharded():
+    """`serve.py --mesh host` on 2 devices (data 1 × model 2, which divides
+    the smoke model's 2 KV heads): the params and KV pools are sharded
+    across the devices, and the greedy streams equal the unsharded
+    server's on the same weights."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", MESH_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "MESH_SERVING_OK" in proc.stdout
