@@ -55,12 +55,7 @@ Tracked columns (parsed from the bench rows; missing rows render as "—"):
     ×-energy win the bench-smoke job gates at ≥ 1.3×, and the
     accuracy-proxy delta (held-out logit KL vs float: mixed − uniform,
     bounded by the search's kl_budget) — deterministic model numbers,
-    platform-free;
-  * (schema v8) the serve-SLO row: p50/p99 time-to-first-token from the
-    runtime.telemetry histograms of a paged-engine drain, plus the
-    telemetry overhead percentage (decode tok/s with the event
-    trace / snapshots / histograms enabled vs disabled — the bench-smoke
-    job gates it < 3 %).
+    platform-free.
 """
 from __future__ import annotations
 
@@ -162,15 +157,6 @@ def extract_metrics(doc: dict) -> dict:
             if kd:
                 out["energy_kl_delta"] = float(kd.group(2)) \
                     - float(kd.group(1))
-        if name.startswith("serve_slo"):
-            tt = re.search(r"ttft_p50_ms=([\d.]+)\|ttft_p99_ms=([\d.]+)",
-                           derived)
-            if tt:
-                out["ttft_p50_ms"] = float(tt.group(1))
-                out["ttft_p99_ms"] = float(tt.group(2))
-            ov = re.search(r"overhead_pct=([+-]?[\d.]+)", derived)
-            if ov:
-                out["telemetry_overhead_pct"] = float(ov.group(1))
         if name.startswith("serve_kv_bytes_occ25"):
             kb = re.search(
                 r"kv_bytes\s+slot=(\d+)\s+paged=(\d+)\s+\(([\d.]+)x", derived)
@@ -224,10 +210,9 @@ def render_markdown(entries: list[dict]) -> str:
         "fused σ ratio | fused noisy µs | serve tok/s | attn-kernel tok/s | "
         "paged KV B @25% | vs slot | score B (kernel) | vs exact | "
         "tuned speedup | prefix lanes | prefill tok saved | spec speedup | "
-        "accept len | mixed pJ/tok | energy win | ΔKL proxy | "
-        "ttft p50 ms | ttft p99 ms | telemetry ovh |",
+        "accept len | mixed pJ/tok | energy win | ΔKL proxy |",
         "|---|---|---|---|---|---|---|---|---|---|---|---|---|---|---|---"
-        "|---|---|---|---|---|---|---|",
+        "|---|---|---|---|",
     ]
     for e in entries:
         m = e.get("metrics", {})
@@ -238,7 +223,7 @@ def render_markdown(entries: list[dict]) -> str:
                             f"({m.get('prefix_win', 0):.1f}×)")
         lines.append(
             "| {} | {} | {} | {} | {} | {} | {} | {} | {} | {} | {} | {} "
-            "| {} | {} | {} | {} | {} | {} | {} | {} | {} | {} | {} |"
+            "| {} | {} | {} | {} | {} | {} | {} | {} |"
             .format(
                 str(e.get("label", "?"))[:24],
                 _fmt(m.get("decode_tok_s"), "{:.0f}"),
@@ -260,9 +245,6 @@ def render_markdown(entries: list[dict]) -> str:
                 _fmt(m.get("mixed_pj_tok"), "{:.0f}"),
                 _fmt(m.get("energy_win"), "{:.2f}×"),
                 _fmt(m.get("energy_kl_delta"), "{:+.4f}"),
-                _fmt(m.get("ttft_p50_ms"), "{:.1f}"),
-                _fmt(m.get("ttft_p99_ms"), "{:.1f}"),
-                _fmt(m.get("telemetry_overhead_pct"), "{:+.2f}%"),
             ))
     shapes = {e.get("metrics", {}).get("decode_shape") for e in entries}
     shapes.discard(None)

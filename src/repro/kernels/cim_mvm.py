@@ -278,4 +278,5 @@ def cim_mvm_grouped(xg: jax.Array, w: jax.Array, seed: jax.Array | None = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="cim_mvm",
     )(*operands)

@@ -305,6 +305,7 @@ def _paged_attn_call(q3, k_pool, v_pool, tables, lens, kvl, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="paged_attn",
     )(tables.astype(jnp.int32), lens.astype(jnp.int32),
       kvl.astype(jnp.int32), q3.astype(jnp.float32),
       *([k_pool] * kblocks), *([v_pool] * kblocks))
@@ -441,6 +442,7 @@ def _fused_write_call(k_pool, v_pool, new_k, new_v, wblk, woff, wval, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="kv_write",
     )(wblk.astype(jnp.int32), woff.astype(jnp.int32),
       wval.astype(jnp.int32),
       # [B, 1, KH, dh] → [B, KH, 1, dh]: the same bytes (C = 1)

@@ -466,16 +466,19 @@ def cow_copy_block(cache: dict, src: jax.Array, dst: jax.Array) -> dict:
 
 def _layer_paged(lp: dict, h: jax.Array, layer_pool: dict, cfg: ModelConfig,
                  *, positions, flat_idx, tables, kv_len):
-    a, new_pool = common.paged_attention_apply(
-        lp["attn"], norm(lp["norm1"], h, cfg), cfg, positions=positions,
-        cache=layer_pool, flat_idx=flat_idx, tables=tables, kv_len=kv_len)
-    h = h + a
-    hn = norm(lp["norm2"], h, cfg)
-    if "router" in lp["ffn"]:
-        f, _ = moe.apply(lp["ffn"], hn, cfg, train=False)
-    else:
-        f = mlp_apply(lp["ffn"], hn, cfg)
-    return h + f, new_pool
+    with jax.named_scope("attn"):
+        a, new_pool = common.paged_attention_apply(
+            lp["attn"], norm(lp["norm1"], h, cfg), cfg, positions=positions,
+            cache=layer_pool, flat_idx=flat_idx, tables=tables,
+            kv_len=kv_len)
+        h = h + a
+    with jax.named_scope("mlp"):
+        hn = norm(lp["norm2"], h, cfg)
+        if "router" in lp["ffn"]:
+            f, _ = moe.apply(lp["ffn"], hn, cfg, train=False)
+        else:
+            f = mlp_apply(lp["ffn"], hn, cfg)
+        return h + f, new_pool
 
 
 def _paged_stack(stacked, pools, h, cfg, *, positions, flat_idx, tables,
@@ -520,38 +523,46 @@ def paged_step(params: dict, tokens: jax.Array, cache: dict,
     window = tables.shape[1] * block_size
     positions = lens[:, None] + jnp.arange(c)[None, :]          # [B, C]
 
-    x = embed_lookup(params["tok"], tokens, cfg)
-    if cfg.pos_embed == "learned":
-        x = x + params["dec_pos"]["pos_embed"][
-            jnp.clip(positions, 0, params["dec_pos"]["pos_embed"].shape[0] - 1)]
+    # named scopes (embed / write_targets / layer_scan{attn, mlp} / head)
+    # name each op's place in a device trace; the scan's own slices of the
+    # stacked pool sit under layer_scan, outside attn and mlp
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["tok"], tokens, cfg)
+        if cfg.pos_embed == "learned":
+            pe = params["dec_pos"]["pos_embed"]
+            x = x + pe[jnp.clip(positions, 0, pe.shape[0] - 1)]
 
     # write targets: logical position → (physical block, offset); lanes
     # beyond `valid` (and beyond the window) land in the trash block
-    pos_w = jnp.minimum(positions, window - 1)
-    blk = jnp.take_along_axis(tables, pos_w // block_size, axis=1)
-    flat_idx = blk * block_size + pos_w % block_size
-    in_valid = jnp.arange(c)[None, :] < valid[:, None]
-    flat_idx = jnp.where(in_valid & (positions < window), flat_idx, 0)
-    kv_len = lens + valid
+    with jax.named_scope("write_targets"):
+        pos_w = jnp.minimum(positions, window - 1)
+        blk = jnp.take_along_axis(tables, pos_w // block_size, axis=1)
+        flat_idx = blk * block_size + pos_w % block_size
+        in_valid = jnp.arange(c)[None, :] < valid[:, None]
+        flat_idx = jnp.where(in_valid & (positions < window), flat_idx, 0)
+        kv_len = lens + valid
 
     new_cache = dict(cache)
-    if "dense_layers" in params:
-        x, np_ = _paged_stack(params["dense_layers"], cache["dense_layers"],
-                              x, cfg, positions=positions, flat_idx=flat_idx,
+    with jax.named_scope("layer_scan"):
+        if "dense_layers" in params:
+            x, np_ = _paged_stack(params["dense_layers"],
+                                  cache["dense_layers"], x, cfg,
+                                  positions=positions, flat_idx=flat_idx,
+                                  tables=tables, kv_len=kv_len)
+            new_cache["dense_layers"] = np_
+        x, np_ = _paged_stack(params["layers"], cache["layers"], x, cfg,
+                              positions=positions, flat_idx=flat_idx,
                               tables=tables, kv_len=kv_len)
-        new_cache["dense_layers"] = np_
-    x, np_ = _paged_stack(params["layers"], cache["layers"], x, cfg,
-                          positions=positions, flat_idx=flat_idx,
-                          tables=tables, kv_len=kv_len)
-    new_cache["layers"] = np_
-    x = norm(params["final_norm"], x, cfg)
-    if all_logits:
-        return unembed(params["tok"], x, cfg), new_cache        # [B, C, V]
-    last = jnp.maximum(valid - 1, 0)                            # [B]
-    h_last = jnp.take_along_axis(
-        x, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = unembed(params["tok"], h_last, cfg)
-    return logits, new_cache
+        new_cache["layers"] = np_
+    with jax.named_scope("head"):
+        x = norm(params["final_norm"], x, cfg)
+        if all_logits:
+            return unembed(params["tok"], x, cfg), new_cache    # [B, C, V]
+        last = jnp.maximum(valid - 1, 0)                        # [B]
+        h_last = jnp.take_along_axis(
+            x, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = unembed(params["tok"], h_last, cfg)
+        return logits, new_cache
 
 
 # ---------------------------------------------------------------------------

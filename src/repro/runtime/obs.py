@@ -6,8 +6,9 @@ it for external tooling:
 * :func:`chrome_trace` — Chrome trace-event JSON (the Perfetto / legacy
   ``chrome://tracing`` format): one track per serving slot carrying
   ``req<rid>`` spans from admit/resume to retire/preempt, plus a scheduler
-  track with per-``step()`` slices and KV-pool counter series.  Load the
-  file at https://ui.perfetto.dev.
+  track with per-``step()`` slices (each step's ``serve.<phase>`` slices
+  nested inside), ``compile`` instants and KV-pool counter series.  Load
+  the file at https://ui.perfetto.dev.
 * :func:`validate_chrome_trace` — structural schema check used by CI on the
   emitted artifact; also runnable directly::
 
@@ -26,6 +27,8 @@ from __future__ import annotations
 import json
 import sys
 
+from repro.runtime.telemetry import PHASES
+
 _PID = 1
 _SCHED_TID = 0
 # ph values the exporter emits; the validator rejects anything else.
@@ -43,8 +46,11 @@ def _us(t: float, t0: float) -> float:
 def chrome_trace(tel, *, process_name: str = "pico-ram serve") -> dict:
     """Render the telemetry ring buffers as a Chrome trace-event document.
 
-    Track layout: tid 0 is the scheduler (step slices, submit instants,
-    KV-pool counters); tid ``slot + 1`` carries that slot's request spans.
+    Track layout: tid 0 is the scheduler (step slices with their phases
+    nested inside, submit and compile instants, KV-pool counters); tid
+    ``slot + 1`` carries that slot's request spans.  A ``compile`` instant
+    at a step's start counts the executables compiled or loaded since the
+    previous step.
     Ring-buffer truncation is handled by construction: an ``E`` whose ``B``
     was evicted is dropped, and spans still open at export time are closed
     with a synthetic ``E`` flagged ``{"truncated": true}``.
@@ -118,12 +124,25 @@ def chrome_trace(tel, *, process_name: str = "pico-ram serve") -> dict:
                         "ts": _us(t_end, t0), "name": stack.pop(),
                         "args": {"truncated": True}})
 
+    compiles = tel.compiles_at_start
     for s in snaps:
         ts = _us(s.t - s.wall_s, t0)
         out.append({"ph": "X", "pid": _PID, "tid": _SCHED_TID, "ts": ts,
                     "dur": round(s.wall_s * 1e6, 3),
                     "name": f"step c={s.c}" + (" spec" if s.all_logits else ""),
                     "args": s.to_dict()})
+        # the phases run back to back inside the step, plan first
+        start = s.t - s.wall_s
+        for phase, sec in zip(PHASES, s.phases):
+            out.append({"ph": "X", "pid": _PID, "tid": _SCHED_TID,
+                        "ts": _us(start, t0), "dur": round(sec * 1e6, 3),
+                        "name": f"serve.{phase}"})
+            start += sec
+        if s.compiles > compiles:
+            out.append({"ph": "i", "pid": _PID, "tid": _SCHED_TID, "ts": ts,
+                        "s": "t", "name": "compile",
+                        "args": {"compiles": s.compiles - compiles}})
+        compiles = s.compiles
         out.append({"ph": "C", "pid": _PID, "tid": _SCHED_TID,
                     "ts": _us(s.t, t0), "name": "kv_pool",
                     "args": {"free": s.blocks_free,
@@ -259,6 +278,19 @@ def prometheus_text(tel, server=None) -> str:
     for name in sorted(k.drafter):
         lines.append(f'picoram_drafter_total{{event="{name}"}} '
                      f"{k.drafter[name]}")
+    lines.append("# HELP picoram_compiles_total executables built by a "
+                 "backend compile or loaded from the persistent cache")
+    lines.append("# TYPE picoram_compiles_total counter")
+    lines.append(f'picoram_compiles_total{{source="backend"}} {k.compiles}')
+    lines.append(f'picoram_compiles_total{{source="cache"}} '
+                 f"{k.cache_loads}")
+    lines.append("# HELP picoram_compile_seconds_total seconds spent in "
+                 "backend compiles and persistent-cache loads")
+    lines.append("# TYPE picoram_compile_seconds_total counter")
+    lines.append(f'picoram_compile_seconds_total{{source="backend"}} '
+                 f"{k.compile_s:g}")
+    lines.append(f'picoram_compile_seconds_total{{source="cache"}} '
+                 f"{k.cache_load_s:g}")
     lines.append("# HELP picoram_mvm_energy_joules_total paper-model CIM "
                  "MVM energy per weight site across traced calls")
     lines.append("# TYPE picoram_mvm_energy_joules_total counter")

@@ -132,7 +132,12 @@ from repro.parallel import sharding
 from repro.runtime.paging import BlockAllocator, PrefixTrie, SlotTables
 from repro.runtime.speculative import SamplingParams, make_drafter, \
     parse_drafter, sample_token, verify_token
-from repro.runtime.telemetry import Telemetry
+from repro.runtime.telemetry import KERNEL_COUNTERS, Telemetry
+
+# JAX's own compile events feed the process-wide compile counter from the
+# moment serving is imported, so a deployment's set-up compiles count too
+jax.monitoring.register_event_duration_secs_listener(
+    KERNEL_COUNTERS.compile_event)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -366,6 +371,12 @@ class Server:
         # on/off flag
         self.telemetry = telemetry if telemetry is not None \
             else Telemetry(enabled=serving.telemetry)
+        # spans land in a device trace as profiler annotations
+        self.telemetry.annotation = jax.profiler.TraceAnnotation
+        with self.telemetry.span("build"):
+            self._build(params, cfg, serving)
+
+    def _build(self, params, cfg: ModelConfig, serving: ServingConfig):
         cfg = cfg.replace(attn_backend=serving.attn)
         if serving.act_scale is not None:
             assert cfg.cim.enabled, "static act_scale needs cim.enabled"
@@ -385,7 +396,9 @@ class Server:
         if serving.prequant:
             assert cfg.cim.enabled, "prequant serving needs cim.enabled"
             from repro.models.quantize import quantize_params
-            params = quantize_params(params, cfg, packed=serving.packed)
+            with self.telemetry.span("quantize"):
+                params = jax.block_until_ready(
+                    quantize_params(params, cfg, packed=serving.packed))
         if sharding.get_mesh() is not None:
             # freshly initialised parameters all sit on the first device;
             # lay them out by the PARAM_RULES so each device holds its shard
@@ -435,19 +448,25 @@ class Server:
                     lambda a: sharding.sharding_for(
                         a.shape, (None, None, "tp", None, None)),
                     jax.eval_shape(init_pool))
-            self.cache = jax.jit(init_pool, out_shardings=pool_shardings)()
-            self._pstep = jax.jit(
-                lambda p, t, c, tb, ln, vd:
-                    self.mod.paged_step(p, t, c, tb, ln, vd, cfg))
+            with self.telemetry.span("pool"):
+                self.cache = jax.block_until_ready(
+                    jax.jit(init_pool, out_shardings=pool_shardings)())
+
+            # named functions, so the compiled modules are named for them
+            def paged_step(p, t, c, tb, ln, vd):
+                return self.mod.paged_step(p, t, c, tb, ln, vd, cfg)
+
+            def paged_step_all(p, t, c, tb, ln, vd):
+                return self.mod.paged_step(p, t, c, tb, ln, vd, cfg,
+                                           all_logits=True)
+
+            self._pstep = jax.jit(paged_step)
             # speculative decoding: the drafter instance (None = off) and
             # the all-positions-logits compilation its verify steps use
             # (one C=spec_k+1 call scores every drafted token at once)
             self.spec_k = serving.spec_k
             self.drafter = make_drafter(serving.drafter, cfg, self.max_len)
-            self._pstep_all = jax.jit(
-                lambda p, t, c, tb, ln, vd:
-                    self.mod.paged_step(p, t, c, tb, ln, vd, cfg,
-                                        all_logits=True))
+            self._pstep_all = jax.jit(paged_step_all)
             # trie capacity watermarks (block counts; 0 = sweep disabled)
             self._trie_hi = self._trie_lo = 0
             if self.trie is not None and serving.trie_watermark is not None:
@@ -482,6 +501,10 @@ class Server:
 
     # -- request lifecycle ---------------------------------------------------
     def submit(self, req: Request) -> int:
+        with self.telemetry.span("submit"):
+            return self._submit(req)
+
+    def _submit(self, req: Request) -> int:
         # reject unservable requests BEFORE queueing: a poison request at
         # the queue head would otherwise either stall admission forever
         # (a footprint larger than the whole pool — run_until_drained
@@ -579,15 +602,17 @@ class Server:
         t0 = self.telemetry.now()
         if self.paged:
             self._step_paged()
-            # trie capacity policy: the watermark sweep runs every step —
-            # including idle ones, where _step_paged returns early — so a
-            # long-lived server's cold prefix cache drains between bursts
-            if self._trie_hi and self.trie is not None:
-                self.metrics.trie_sweep_freed += self.trie.sweep(
-                    self.alloc, self._trie_hi, self._trie_lo)
         else:
             self._step_slots()
         self.metrics.wall_s += self.telemetry.now() - t0
+
+    def _sweep_trie(self):
+        # trie capacity policy: the watermark sweep runs every step —
+        # idle ones included — so a long-lived server's cold prefix cache
+        # drains between bursts
+        if self._trie_hi and self.trie is not None:
+            self.metrics.trie_sweep_freed += self.trie.sweep(
+                self.alloc, self._trie_hi, self._trie_lo)
 
     def _step_slots(self):
         """Legacy engine: one decode step for all slots."""
@@ -760,158 +785,173 @@ class Server:
         return need, copies
 
     def _step_paged(self):
+        tel = self.telemetry
         if not any(r is not None for r in self.slot_req):
+            with tel.span("admit"):
+                self._sweep_trie()
             return
-        t_begin = self.telemetry.now()
-        # plan the step; preempt the newest-admitted lane while the pool
-        # cannot back every write (evictable trie entries count as room —
-        # they are freed below, before acquiring)
-        while True:
-            active = [s for s in range(self.n_slots) if self.slot_req[s]]
-            if not active:
-                return
-            decode_lanes, dropped, takes, starved = self._schedule(active)
-            spec = self._plan_spec(decode_lanes)
-            valid_map = {s: 1 + len(spec.get(s, ())) for s in decode_lanes}
-            valid_map.update(takes)
-            need, copies = self._write_plan(valid_map)
-            if need <= self._available() or len(active) == 1:
-                break
-            # newest admission loses: FIFO fairness, and its trie overlap
-            # makes its resume the cheapest recompute
-            victim = max(active, key=lambda s: int(self._slot_seq[s]))
-            self._preempt(victim)
-        self._rr += 1
-        self.metrics.stalled_decodes += dropped
-        self.metrics.stalled_prefills += starved
-        self.metrics.peak_active = max(self.metrics.peak_active, len(active))
-        self.metrics.peak_decode_lanes = max(self.metrics.peak_decode_lanes,
-                                             len(decode_lanes))
-        # make room, then privatize shared write targets, then back the
-        # new positions. With one active lane the submit-time worst-case
-        # check guarantees this always fits (see _blocks_worst_case).
-        shortfall = need - self.alloc.stats.free
-        if shortfall > 0 and self.trie is not None:
-            self.trie.evict(shortfall, self.alloc)
-        if not self.alloc.can_acquire(need):
-            raise RuntimeError(
-                f"pool cannot back this step: need {need} blocks, "
-                f"free {self.alloc.stats.free} — scheduler invariant "
-                "violated")
-        for s, j, b in copies:
-            [nb] = self.alloc.acquire(1)
-            self.cache = self._cow(self.cache, jnp.asarray(b, jnp.int32),
-                                   jnp.asarray(nb, jnp.int32))
-            self.tables.replace(s, j, nb, self.alloc)
-            self.metrics.cow_forks += 1
-            self.telemetry.cow_fork(self.slot_req[s].rid, s,
-                                    self.telemetry.now(), b, nb)
-        for s, v in valid_map.items():
-            if v:
-                self.tables.grow(s, int(self.tables.lens[s]) + v,
-                                 self.alloc)
-        # chunk width: steps whose prefill lanes are all budget-starved run
-        # the cheap C=1 decode compilation; spec verify lanes always stamp
-        # C=spec_k+1 (per-lane clamps shrink `valid`, never the traced
-        # shape, so the compiled-shape set stays bounded)
-        c = 1
-        if takes:
-            c = self.prefill_chunk
-        if spec:
-            c = max(c, self.spec_k + 1)
-        toks = np.zeros((self.n_slots, c), np.int32)
-        valid = np.zeros(self.n_slots, np.int32)
-        for s in decode_lanes:
-            toks[s, 0] = self.slot_req[s].output[-1]
-            drafts = spec.get(s, ())
-            toks[s, 1:1 + len(drafts)] = drafts
-            valid[s] = 1 + len(drafts)
-        for s, take in takes.items():
-            done = int(self._pf_done[s])
-            src = self._pf_src[s]
-            toks[s, :take] = src[done:done + take]
-            valid[s] = take
+        t_begin = tel.now()
+        with tel.span("plan"):
+            # plan the step; preempt the newest-admitted lane while the pool
+            # cannot back every write (evictable trie entries count as room —
+            # they are freed below, before acquiring)
+            while True:
+                active = [s for s in range(self.n_slots) if self.slot_req[s]]
+                if not active:
+                    return
+                decode_lanes, dropped, takes, starved = self._schedule(active)
+                spec = self._plan_spec(decode_lanes)
+                valid_map = {s: 1 + len(spec.get(s, ())) for s in decode_lanes}
+                valid_map.update(takes)
+                need, copies = self._write_plan(valid_map)
+                if need <= self._available() or len(active) == 1:
+                    break
+                # newest admission loses: FIFO fairness, and its trie overlap
+                # makes its resume the cheapest recompute
+                victim = max(active, key=lambda s: int(self._slot_seq[s]))
+                self._preempt(victim)
+            self._rr += 1
+            self.metrics.stalled_decodes += dropped
+            self.metrics.stalled_prefills += starved
+            self.metrics.peak_active = max(self.metrics.peak_active,
+                                           len(active))
+            self.metrics.peak_decode_lanes = max(
+                self.metrics.peak_decode_lanes, len(decode_lanes))
+            # make room, then privatize shared write targets, then back the
+            # new positions. With one active lane the submit-time worst-case
+            # check guarantees this always fits (see _blocks_worst_case).
+            shortfall = need - self.alloc.stats.free
+            if shortfall > 0 and self.trie is not None:
+                self.trie.evict(shortfall, self.alloc)
+            if not self.alloc.can_acquire(need):
+                raise RuntimeError(
+                    f"pool cannot back this step: need {need} blocks, "
+                    f"free {self.alloc.stats.free} — scheduler invariant "
+                    "violated")
+            for s, j, b in copies:
+                [nb] = self.alloc.acquire(1)
+                self.cache = self._cow(self.cache, jnp.asarray(b, jnp.int32),
+                                       jnp.asarray(nb, jnp.int32))
+                self.tables.replace(s, j, nb, self.alloc)
+                self.metrics.cow_forks += 1
+                self.telemetry.cow_fork(self.slot_req[s].rid, s,
+                                        self.telemetry.now(), b, nb)
+            for s, v in valid_map.items():
+                if v:
+                    self.tables.grow(s, int(self.tables.lens[s]) + v,
+                                     self.alloc)
+            # chunk width: steps whose prefill lanes are all budget-starved
+            # run the cheap C=1 decode compilation; spec verify lanes always
+            # stamp C=spec_k+1 (per-lane clamps shrink `valid`, never the
+            # traced shape, so the compiled-shape set stays bounded)
+            c = 1
+            if takes:
+                c = self.prefill_chunk
+            if spec:
+                c = max(c, self.spec_k + 1)
+            toks = np.zeros((self.n_slots, c), np.int32)
+            valid = np.zeros(self.n_slots, np.int32)
+            for s in decode_lanes:
+                toks[s, 0] = self.slot_req[s].output[-1]
+                drafts = spec.get(s, ())
+                toks[s, 1:1 + len(drafts)] = drafts
+                valid[s] = 1 + len(drafts)
+            for s, take in takes.items():
+                done = int(self._pf_done[s])
+                src = self._pf_src[s]
+                toks[s, :take] = src[done:done + take]
+                valid[s] = take
+            toks_d, valid_d = jnp.asarray(toks), jnp.asarray(valid)
+            tables_d = jnp.asarray(self.tables.tables)
+            lens_d = jnp.asarray(self.tables.lens)
         # verify steps need the logits at EVERY chunk position (one row
         # per drafted token plus the bonus); everything else keeps the
         # last-position compilation
         pstep = self._pstep_all if spec else self._pstep
-        logits, self.cache = pstep(
-            self.params, jnp.asarray(toks), self.cache,
-            jnp.asarray(self.tables.tables), jnp.asarray(self.tables.lens),
-            jnp.asarray(valid))
-        rows = np.asarray(logits)               # [B, V] or [B, C, V]
-        now = self.telemetry.now()
-        dec_lanes: list = []                    # plain-decode emissions this
-        retires: list = []                      # step, batched into ONE ring
-        for s in active:                        # event after the lane loop
-            if not valid[s]:
-                continue
-            req = self.slot_req[s]
-            if s in takes:
-                self.tables.lens[s] += int(valid[s])
-                self._pf_done[s] += int(valid[s])
-                self.metrics.prefill_tokens += int(valid[s])
-                self.telemetry.prefill_chunk(req.rid, s, now, int(valid[s]),
-                                             int(self._pf_done[s]),
-                                             len(self._pf_src[s]))
-                if self._pf_done[s] == len(self._pf_src[s]):
-                    row = rows[s, int(valid[s]) - 1] if rows.ndim == 3 \
-                        else rows[s]
-                    # emission index = len(output): 0 for a fresh prompt,
-                    # the resume index after preemption — either way the
-                    # same (seed, index) PRNG key plain decode would use
-                    req.output.append(
-                        sample_token(row, req.sampling, len(req.output)))
-                    if not req.t_first:
-                        req.t_first = now
-                        self.telemetry.first_token(req.rid, s, now,
-                                                   req.t_submit)
-                    else:
-                        # resume completion re-emits a token: the ITL
-                        # sample spans the preemption gap on purpose
-                        self.telemetry.emission(req.rid, s, now)
-                    self._register_prefix(s)
-                    self._stash_forks(s)
-                    # one-at-a-time semantics: exhaustion AND EOS apply to
-                    # the prefill-emitted token too (the legacy engine
-                    # checks neither here — see the module docstring)
-                    if (len(req.output) >= req.max_new_tokens
-                            or (req.eos_id is not None
-                                and req.output[-1] == req.eos_id)):
-                        self._retire_paged(s, now)
-                continue
-            if s in spec:
-                self._apply_verify(s, rows[s], spec[s], now)
-                continue
-            self.tables.lens[s] += 1
-            row = rows[s, 0] if rows.ndim == 3 else rows[s]
-            nxt = sample_token(row, req.sampling, len(req.output))
-            req.output.append(nxt)
-            self.metrics.decode_tokens += 1
-            dec_lanes.append((req.rid, s))
-            exhausted = len(req.output) >= req.max_new_tokens
-            hit_eos = req.eos_id is not None and nxt == req.eos_id
-            full = int(self.tables.lens[s]) + 1 >= self.max_len - 1
-            if exhausted or hit_eos or full:
-                retires.append(s)
-        # one batched decode event for the whole step's plain emissions
-        # (per-lane ITL samples are still recorded inside), THEN the
-        # retires so each rid's ring ends with its retire event
-        self.telemetry.decode_step(dec_lanes, now)
-        for s in retires:
-            self._retire_paged(s, now)
-        self.steps_run += 1
-        self.metrics.steps += 1
+        with tel.span("device", c=c):
+            logits, self.cache = pstep(self.params, toks_d, self.cache,
+                                       tables_d, lens_d, valid_d)
+            jax.block_until_ready(logits)
+        with tel.span("fetch"):
+            rows = np.asarray(logits)           # [B, V] or [B, C, V]
+        with tel.span("sample"):
+            now = self.telemetry.now()
+            dec_lanes: list = []                # plain-decode emissions this
+            retires: list = []                  # step, batched into ONE ring
+            for s in active:                    # event after the lane loop
+                if not valid[s]:
+                    continue
+                req = self.slot_req[s]
+                if s in takes:
+                    self.tables.lens[s] += int(valid[s])
+                    self._pf_done[s] += int(valid[s])
+                    self.metrics.prefill_tokens += int(valid[s])
+                    self.telemetry.prefill_chunk(
+                        req.rid, s, now, int(valid[s]),
+                        int(self._pf_done[s]), len(self._pf_src[s]))
+                    if self._pf_done[s] == len(self._pf_src[s]):
+                        row = rows[s, int(valid[s]) - 1] if rows.ndim == 3 \
+                            else rows[s]
+                        # emission index = len(output): 0 for a fresh prompt,
+                        # the resume index after preemption — either way the
+                        # same (seed, index) PRNG key plain decode would use
+                        req.output.append(
+                            sample_token(row, req.sampling, len(req.output)))
+                        if not req.t_first:
+                            req.t_first = now
+                            self.telemetry.first_token(req.rid, s, now,
+                                                       req.t_submit)
+                        else:
+                            # resume completion re-emits a token: the ITL
+                            # sample spans the preemption gap on purpose
+                            self.telemetry.emission(req.rid, s, now)
+                        self._register_prefix(s)
+                        self._stash_forks(s)
+                        # one-at-a-time semantics: exhaustion AND EOS apply
+                        # to the prefill-emitted token too (the legacy
+                        # engine checks neither here — see the module
+                        # docstring)
+                        if (len(req.output) >= req.max_new_tokens
+                                or (req.eos_id is not None
+                                    and req.output[-1] == req.eos_id)):
+                            self._retire_paged(s, now)
+                    continue
+                if s in spec:
+                    self._apply_verify(s, rows[s], spec[s], now)
+                    continue
+                self.tables.lens[s] += 1
+                row = rows[s, 0] if rows.ndim == 3 else rows[s]
+                nxt = sample_token(row, req.sampling, len(req.output))
+                req.output.append(nxt)
+                self.metrics.decode_tokens += 1
+                dec_lanes.append((req.rid, s))
+                exhausted = len(req.output) >= req.max_new_tokens
+                hit_eos = req.eos_id is not None and nxt == req.eos_id
+                full = int(self.tables.lens[s]) + 1 >= self.max_len - 1
+                if exhausted or hit_eos or full:
+                    retires.append(s)
+            # one batched decode event for the whole step's plain emissions
+            # (per-lane ITL samples are still recorded inside), THEN the
+            # retires so each rid's ring ends with its retire event
+            self.telemetry.decode_step(dec_lanes, now)
+            for s in retires:
+                self._retire_paged(s, now)
+            self.steps_run += 1
+            self.metrics.steps += 1
         # sample pool composition + scheduler state once per step; the
         # pool dict also lands on ServerMetrics so to_dict() reflects the
         # post-run split even with telemetry disabled
-        t_end = self.telemetry.now()
-        pool = self._pool_stats()
-        self.metrics.pool = pool
-        # positional on purpose (field order == StepSnapshot): the 16-kwarg
-        # binding was the most expensive part of the per-step telemetry
-        # call and both legs pay it before the enabled check
-        self.telemetry.step_snapshot(
+        with tel.span("admit"):
+            pool = self._pool_stats()
+            self.metrics.pool = pool
+            self._admit()
+            self._sweep_trie()
+        t_end = tel.now()
+        # after the admit span, whose duration it stamps; positional on
+        # purpose (field order == StepSnapshot): the 16-kwarg binding was
+        # the most expensive part of the per-step telemetry call and both
+        # legs pay it before the enabled check
+        tel.step_snapshot(
             self.steps_run, t_end, t_end - t_begin,             # step/t/wall
             len(active), len(decode_lanes), len(takes),         # lane mix
             len(spec), c, bool(spec),                           # shape
@@ -919,7 +959,6 @@ class Server:
             pool["blocks_free"], pool["blocks_private"],        # pool split
             pool["blocks_shared"], pool["blocks_cached_cold"],
             pool["trie_entries"])
-        self._admit()
 
     def _plan_spec(self, decode_lanes) -> dict[int, list[int]]:
         """Draft proposals for this step's decode lanes: {slot: tokens}.

@@ -18,6 +18,13 @@ Three kinds of state live here:
 * **Histograms** — fixed-bucket :class:`Histogram` instances for TTFT, ITL,
   spec-decode accept length and step wall time, with p50/p90/p99 estimation
   by linear interpolation inside the winning bucket.
+* **Spans** — :meth:`Telemetry.span` times one phase of the host's work
+  (``serve.plan`` … ``serve.admit`` inside a paged step, ``serve.build`` and
+  its children at construction, ``serve.submit``) on the telemetry clock,
+  and opens a profiler annotation of the same name so the phase also lands
+  in a device trace, on the profiler's clock.  The annotation class is
+  injected (the Server passes ``jax.profiler.TraceAnnotation``); the default
+  is a no-op, which keeps this module free of jax.
 
 Kernel/engine-layer counters (`KERNEL_COUNTERS`) are a process-wide
 singleton because ``execute_mvm`` dispatch happens inside ``jax.jit``
@@ -25,7 +32,9 @@ tracing, far away from any `Server` instance.  Those counters count TRACED
 calls — jit caching means one count per compiled shape, not one per executed
 step — which is exactly what you want for "which backend did the dispatcher
 pick" and "what would one traced step cost in CIM energy" questions, and is
-documented on the class.
+documented on the class.  Its compile counter is the exception: it counts
+real backend compiles and persistent-cache loads, fed by a
+``jax.monitoring`` listener that ``runtime.server`` registers on import.
 
 Exporters (Chrome trace-event JSON for Perfetto, Prometheus text
 exposition, JSONL) live in :mod:`repro.runtime.obs`.
@@ -157,8 +166,7 @@ EVENT_KINDS = frozenset({
 
 # Event and StepSnapshot are NamedTuples, not dataclasses: construction is
 # on the decode hot path (one Event per emitted token, one StepSnapshot per
-# step) and tuple construction is several times cheaper — the difference
-# shows directly in the serve_slo telemetry-overhead gate.
+# step) and tuple construction is several times cheaper.
 class Event(NamedTuple):
     """One structured trace event.  ``data`` holds kind-specific fields."""
 
@@ -176,8 +184,15 @@ class Event(NamedTuple):
         return d
 
 
+#: The host phases of one paged ``step()``, in order; together they cover
+#: the whole step.  Each is a ``serve.<phase>`` span (see Telemetry.span).
+PHASES = ("plan", "device", "fetch", "sample", "admit")
+
+
 class StepSnapshot(NamedTuple):
-    """Scheduler/pool state sampled once per paged ``step()``."""
+    """Scheduler/pool state sampled once per paged ``step()``.  ``t`` is
+    the step's end and ``wall_s`` its whole duration, every phase
+    included."""
 
     step: int
     t: float
@@ -195,6 +210,8 @@ class StepSnapshot(NamedTuple):
     blocks_shared: int
     blocks_cached_cold: int
     trie_entries: int
+    compiles: int          # KERNEL_COUNTERS.compile_events after the step
+    phases: tuple          # seconds per PHASES entry, in that order
 
     def to_dict(self) -> dict:
         return {
@@ -211,11 +228,18 @@ class StepSnapshot(NamedTuple):
             "blocks_shared": self.blocks_shared,
             "blocks_cached_cold": self.blocks_cached_cold,
             "trie_entries": self.trie_entries,
+            "compiles": self.compiles,
+            "phases": dict(zip(PHASES, self.phases)),
         }
 
 
 # ---------------------------------------------------------------------------
 # kernel / engine counters
+
+
+# jax.monitoring events the compile counter listens to
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 @dataclass
@@ -229,7 +253,12 @@ class KernelCounters:
     the dispatcher pick for each shape family" and "what does one traced
     step cost in CIM energy per weight site", not "how many MVMs ran".
     Host-side counters (drafter, tune-cache, fallback warnings) do count
-    real calls.
+    real calls, and so does the compile counter: :meth:`compile_event` is a
+    ``jax.monitoring`` duration listener, so ``compiles`` / ``compile_s``
+    count backend compiles and ``cache_loads`` / ``cache_load_s``
+    executables loaded from the persistent compilation cache.  Each event
+    is also logged with its end time on ``time.perf_counter``, so a reader
+    can tell set-up compiles from those inside a measured window.
     """
 
     backend_dispatch: Counter = field(default_factory=Counter)
@@ -241,6 +270,39 @@ class KernelCounters:
     # products per traced call (rows x out-cols), "energy_j": paper-model
     # Eq.4 energy for those dots}
     site_energy: dict = field(default_factory=dict)
+    compiles: int = 0
+    compile_s: float = 0.0
+    cache_loads: int = 0
+    cache_load_s: float = 0.0
+    # (t_end, "compile" | "cache_load", seconds), newest last
+    compile_log: deque = field(default_factory=lambda: deque(maxlen=4096))
+    _cache_hit: bool = False
+
+    @property
+    def compile_events(self) -> int:
+        """Executables built or loaded so far: a step that raises this
+        recompiled (or reloaded) something."""
+        return self.compiles + self.cache_loads
+
+    def compile_event(self, event: str, seconds: float, **_) -> None:
+        """``jax.monitoring`` duration listener.  JAX times every
+        ``compile_or_get_cached`` as one backend-compile event; a cache hit
+        first reports its retrieval, so the backend event that follows is
+        a load, not a compile."""
+        if event == CACHE_LOAD_EVENT:
+            self._cache_hit = True
+            return
+        if event != BACKEND_COMPILE_EVENT:
+            return
+        kind = "cache_load" if self._cache_hit else "compile"
+        self._cache_hit = False
+        if kind == "compile":
+            self.compiles += 1
+            self.compile_s += seconds
+        else:
+            self.cache_loads += 1
+            self.cache_load_s += seconds
+        self.compile_log.append((time.perf_counter(), kind, seconds))
 
     def count_backend(self, name: str) -> None:
         self.backend_dispatch[name] += 1
@@ -272,6 +334,10 @@ class KernelCounters:
             "fallback_warnings": self.fallback_warnings,
             "drafter": dict(self.drafter),
             "site_energy": {k: dict(v) for k, v in self.site_energy.items()},
+            "compiles": self.compiles,
+            "compile_s": self.compile_s,
+            "cache_loads": self.cache_loads,
+            "cache_load_s": self.cache_load_s,
         }
 
     def reset(self) -> None:
@@ -281,6 +347,10 @@ class KernelCounters:
         self.fallback_warnings = 0
         self.drafter.clear()
         self.site_energy.clear()
+        self.compiles = self.cache_loads = 0
+        self.compile_s = self.cache_load_s = 0.0
+        self.compile_log.clear()
+        self._cache_hit = False
 
 
 #: Singleton the engine/kernel hooks write into.  Reset via
@@ -317,9 +387,16 @@ class Telemetry:
     Every read surface (``events``, ``snapshots``, ``counters``, the
     histograms, :meth:`summary`) is a property/method that flushes
     first, so readers never observe the buffering; a size threshold
-    (``_FLUSH_AT``) bounds pending memory on export-free runs.  The
-    serve_slo bench gates the hot-phase cost; the deferred replay runs
-    at export time (or amortised ~once per 1400 steps mid-serve).
+    (``_FLUSH_AT``) bounds pending memory on export-free runs; the
+    deferred replay runs at export time (or amortised ~once per 1400
+    steps mid-serve).
+
+    :meth:`span` is the one timing primitive: ``with tel.span("plan"):``
+    opens the profiler annotation ``serve.plan`` (``annotation`` is the
+    injected class, a no-op by default) and, when enabled, stores the
+    phase's duration.  The five :data:`PHASES` land in that step's
+    :class:`StepSnapshot`; every other span name accumulates a count and
+    seconds, reported by :meth:`summary` under ``"spans"``.
     """
 
     def __init__(self, *, enabled: bool = True, clock=time.monotonic,
@@ -335,6 +412,11 @@ class Telemetry:
         self._accept_len = Histogram(ACCEPT_BUCKETS)
         self._step_wall = Histogram(STEP_BUCKETS)
         self.kernel = KERNEL_COUNTERS
+        self.annotation = _NoAnnotation
+        # compile events before the first snapshot (the exporter's baseline)
+        self.compiles_at_start = self.kernel.compile_events
+        self._phase_s = [0.0] * len(PHASES)     # this step's phases
+        self._span_s: dict[str, list] = {}      # other spans: [n, seconds]
         self._last_emit: dict[int, float] = {}   # rid -> t (replay state)
         self._pending: list[tuple] = []
         self._replay = {
@@ -349,6 +431,24 @@ class Telemetry:
     # -- clock ------------------------------------------------------------
     def now(self) -> float:
         return self.clock()
+
+    # -- spans ------------------------------------------------------------
+    def span(self, name: str, **args) -> "_Span":
+        """Time one phase: a ``serve.<name>`` profiler annotation (``args``
+        become its metadata) and, when enabled, its duration on the
+        telemetry clock."""
+        return _Span(self, name, self.annotation("serve." + name, **args))
+
+    def _end_span(self, name: str, seconds: float) -> None:
+        i = _PHASE_INDEX.get(name)
+        if i is not None:
+            self._phase_s[i] = seconds
+            return
+        rec = self._span_s.get(name)
+        if rec is None:
+            rec = self._span_s[name] = [0, 0.0]
+        rec[0] += 1
+        rec[1] += seconds
 
     # -- hot-phase hooks (called by Server; append raw tuples only) -------
     def event(self, kind: str, rid: int = -1, slot: int = -1,
@@ -465,15 +565,18 @@ class Telemetry:
                       token_budget, blocks_free, blocks_private,
                       blocks_shared, blocks_cached_cold,
                       trie_entries) -> None:
-        # explicit parameter list (not **kw): the kwargs repack showed up
-        # in the serve_slo overhead gate, and both legs pay the binding
+        """Record the step; stamps the compile count and the step's phase
+        durations itself, so call it after the step's last span."""
+        # explicit parameter list (not **kw): the kwargs repack is the
+        # costliest part of a per-step call, and the off leg pays it too
         if not self.enabled:
             return
         p = self._pending
         p.append(("snap", step, t, wall_s, active, decode_lanes,
                   prefill_lanes, spec_lanes, c, all_logits, budget_used,
                   token_budget, blocks_free, blocks_private, blocks_shared,
-                  blocks_cached_cold, trie_entries))
+                  blocks_cached_cold, trie_entries,
+                  self.kernel.compile_events, tuple(self._phase_s)))
         if len(p) >= _FLUSH_AT:
             self._flush()
 
@@ -591,7 +694,8 @@ class Telemetry:
 
     # -- management -------------------------------------------------------
     def reset(self) -> None:
-        """Clear per-run state (events, snapshots, histograms, ITL marks).
+        """Clear per-run state (events, snapshots, histograms, ITL marks,
+        span totals).
 
         Does NOT touch :data:`KERNEL_COUNTERS` — that singleton is shared
         across servers and owned by whoever resets it explicitly.
@@ -600,6 +704,8 @@ class Telemetry:
         self._events.clear()
         self._snapshots.clear()
         self._counters.clear()
+        self._span_s.clear()
+        self.compiles_at_start = self.kernel.compile_events
         self._last_emit.clear()
         self._ttft = Histogram(TTFT_BUCKETS)
         self._itl = Histogram(ITL_BUCKETS)
@@ -614,5 +720,48 @@ class Telemetry:
             "itl": self._itl.summary(),
             "accept_len": self._accept_len.summary(),
             "step_wall": self._step_wall.summary(),
+            "spans": {k: {"count": n, "seconds": sec}
+                      for k, (n, sec) in self._span_s.items()},
             "kernel": self.kernel.snapshot(),
         }
+
+
+class _NoAnnotation:
+    """Default profiler annotation: does nothing (the Server injects
+    ``jax.profiler.TraceAnnotation``)."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str, **args):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_PHASE_INDEX = {p: i for i, p in enumerate(PHASES)}
+
+
+class _Span:
+    """Context manager :meth:`Telemetry.span` returns."""
+
+    __slots__ = ("tel", "name", "ann", "t0")
+
+    def __init__(self, tel: Telemetry, name: str, ann):
+        self.tel, self.name, self.ann = tel, name, ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        if self.tel.enabled:
+            self.t0 = self.tel.clock()
+        return self
+
+    def __exit__(self, *exc):
+        tel = self.tel
+        if tel.enabled:
+            tel._end_span(self.name, tel.clock() - self.t0)
+        self.ann.__exit__(*exc)
+        return False

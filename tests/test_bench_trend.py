@@ -64,13 +64,6 @@ def _doc(us_decode=400.0, ratio=1.02):
              "derived": "uniform_pj_tok=26692.7|mixed_pj_tok=18448.8|"
                         "energy_win=1.447x|kl_uniform=2.2014|"
                         "kl_mixed=2.2163|kl_budget=0.080|levels=wq:128"},
-            # schema-v8 serve-SLO row: telemetry-histogram TTFT
-            # percentiles + the telemetry-on/off overhead percentage
-            {"name": "serve_slo_paged_s4_r6", "us": 5200.0,
-             "derived": "ttft_p50_ms=104.20|ttft_p99_ms=310.55|"
-                        "itl_p50_ms=4.10|itl_p99_ms=9.80|"
-                        "tok_s_on=182.0|tok_s_off=184.5|"
-                        "overhead_pct=+1.36"},
         ],
     }
 
@@ -113,10 +106,6 @@ def test_extract_metrics():
     assert m["mixed_pj_tok"] == pytest.approx(18448.8)
     assert m["energy_win"] == pytest.approx(1.447)
     assert m["energy_kl_delta"] == pytest.approx(2.2163 - 2.2014)
-    # schema-v8 serve-SLO row
-    assert m["ttft_p50_ms"] == pytest.approx(104.20)
-    assert m["ttft_p99_ms"] == pytest.approx(310.55)
-    assert m["telemetry_overhead_pct"] == pytest.approx(1.36)
 
 
 def test_extract_metrics_tolerates_missing_rows():
@@ -155,11 +144,9 @@ def test_history_append_and_render(tmp_path):
     assert "7 vs 1 (7.0×)" in md and "336" in md  # v5 shared-prefix row
     assert "2.23×" in md and "2.87" in md         # v6 spec-decode row
     assert "1.45×" in md and "+0.0149" in md      # v7 energy-pareto row
-    assert "104.2" in md and "310.6" in md        # v8 serve-SLO TTFT
-    assert "+1.36%" in md                         # v8 telemetry overhead
-    # table stays well-formed: every data row has the 23 columns
+    # table stays well-formed: every data row has the 20 columns
     rows = [ln for ln in md.splitlines() if ln.startswith("| run-")]
-    assert all(ln.count("|") == 24 for ln in rows)
+    assert all(ln.count("|") == 21 for ln in rows)
 
 
 def test_one_shot_mode(tmp_path):

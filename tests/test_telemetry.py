@@ -31,7 +31,10 @@ from repro.configs.registry import SMOKES
 from repro.models import registry
 from repro.runtime import obs
 from repro.runtime.server import Request, Server, ServingConfig
-from repro.runtime.telemetry import (ACCEPT_BUCKETS, Histogram, Telemetry)
+from repro.runtime.telemetry import (ACCEPT_BUCKETS, BACKEND_COMPILE_EVENT,
+                                     CACHE_LOAD_EVENT, KERNEL_COUNTERS,
+                                     PHASES, Histogram, KernelCounters,
+                                     Telemetry)
 
 
 class FakeClock:
@@ -305,8 +308,13 @@ def test_telemetry_off_serves_identically():
         outs.append([r.output for r in reqs])
         if on:
             assert srv.telemetry.events
+            assert all(len(s.phases) == len(PHASES)
+                       for s in srv.telemetry.snapshots)
         else:
             assert not srv.telemetry.events and srv.telemetry.ttft.n == 0
+            # no phases, spans or snapshots are recorded either
+            assert not srv.telemetry.snapshots
+            assert srv.telemetry.summary()["spans"] == {}
             # the pool split still lands on the metrics bag
             assert "blocks_free" in srv.metrics.to_dict()
     assert outs[0] == outs[1]
@@ -355,3 +363,178 @@ def test_accept_buckets_cover_spec_k():
     # integer accept counts land exactly on bucket edges 0..8
     assert ACCEPT_BUCKETS[0] == 0.0 and ACCEPT_BUCKETS[-1] >= 8.0
     assert list(itertools.islice(iter(ACCEPT_BUCKETS), 3)) == [0.0, 1.0, 2.0]
+
+
+# ---------------------------------------------------------------------------
+# spans and the compile counter
+
+
+class Recorder:
+    """Profiler-annotation stand-in that logs each span's enter and exit."""
+
+    log: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        Recorder.log.append(("enter", self.name, self.args))
+
+    def __exit__(self, *exc):
+        Recorder.log.append(("exit", self.name, self.args))
+
+
+def _small_server(telemetry, **kw):
+    cfg = SMOKES["internlm2-1.8b"].replace(dtype="float32")
+    params = registry.init_params(jax.random.PRNGKey(0), cfg, max_seq=32)
+    serving = dict(n_slots=2, max_len=32, paged=True, block_size=4,
+                   prefill_chunk=4, attn="exact")
+    serving.update(kw)
+    return Server(params, cfg, ServingConfig(**serving),
+                  telemetry=telemetry), cfg
+
+
+def test_paged_steps_record_five_phases_in_order():
+    srv, cfg = _small_server(Telemetry(clock=FakeClock()))
+    srv.telemetry.annotation = Recorder
+    Recorder.log = []
+    rng = np.random.RandomState(2)
+    for _ in range(2):
+        srv.submit(Request(prompt=rng.randint(0, cfg.vocab, 6).tolist(),
+                           max_new_tokens=4))
+    srv.run_until_drained()
+    snaps = list(srv.telemetry.snapshots)
+    assert len(snaps) == srv.metrics.steps
+    for s in snaps:
+        assert len(s.phases) == len(PHASES)
+        assert all(p > 0 for p in s.phases)     # every clock read ticks
+        assert sum(s.phases) <= s.wall_s
+    # each step opens its phases one after another, in PHASES order,
+    # none nested in another; the device span carries the chunk width
+    entered = [name for kind, name, _ in Recorder.log
+               if kind == "enter" and name != "serve.submit"]
+    per_step = [entered[i:i + len(PHASES)]
+                for i in range(0, len(entered), len(PHASES))]
+    assert len(per_step) == len(snaps)
+    assert all(p == [f"serve.{x}" for x in PHASES] for p in per_step)
+    depth = 0
+    for kind, name, _ in Recorder.log:
+        depth += 1 if kind == "enter" else -1
+        assert 0 <= depth <= 1, name
+    widths = [a["c"] for kind, name, a in Recorder.log
+              if kind == "enter" and name == "serve.device"]
+    assert widths == [s.c for s in snaps]
+
+
+def test_build_and_submit_spans_in_summary():
+    from repro.core.cim_matmul import CIMConfig
+
+    cfg = SMOKES["internlm2-1.8b"].replace(dtype="float32",
+                                           cim=CIMConfig(enabled=True))
+    params = registry.init_params(jax.random.PRNGKey(0), cfg, max_seq=32)
+    srv = Server(params, cfg, ServingConfig(
+        n_slots=2, max_len=32, paged=True, block_size=4, prequant=True),
+        telemetry=Telemetry(clock=FakeClock()))
+    spans = srv.telemetry.summary()["spans"]
+    assert {k: v["count"] for k, v in spans.items()} == \
+        {"build": 1, "quantize": 1, "pool": 1}
+    # quantize and pool are children of build
+    assert spans["quantize"]["seconds"] + spans["pool"]["seconds"] \
+        < spans["build"]["seconds"]
+    srv.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
+    assert srv.telemetry.summary()["spans"]["submit"]["count"] == 1
+
+
+def test_phase_spans_in_a_profiler_trace(tmp_path):
+    """A few steps under jax.profiler.trace: the host spans
+    serve.plan/device/fetch/sample/admit, in order, inside each step."""
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    from benchlib import trace as trace_mod
+
+    srv, cfg = _small_server(None)
+    for _ in range(2):
+        srv.submit(Request(prompt=[5, 6, 7, 8, 9, 10], max_new_tokens=6))
+    srv.step()                      # compile outside the trace
+    srv.step()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            with jax.profiler.TraceAnnotation("test.step"):
+                srv.step()
+    host = trace_mod.load(str(tmp_path))["host"]
+    steps = sorted((s, e) for n, s, e in host if n == "test.step")
+    assert len(steps) == 3
+    for lo, hi in steps:
+        inside = sorted((s, e, n) for n, s, e in host
+                        if n.startswith("serve.") and lo <= s and e <= hi)
+        assert [n for _, _, n in inside] == [f"serve.{p}" for p in PHASES]
+        ends = [e for _, e, _ in inside]
+        starts = [s for s, _, _ in inside]
+        assert all(e <= s for e, s in zip(ends, starts[1:]))
+
+
+def test_compile_counter_counts_new_widths_once():
+    """The running compile count stamped into each snapshot rises on the
+    first step of a new chunk width and stays flat on repeats."""
+    srv, cfg = _small_server(None, prefill_chunk=8)
+    srv.submit(Request(prompt=list(range(1, 20)), max_new_tokens=6))
+    srv.run_until_drained()
+    snaps = list(srv.telemetry.snapshots)
+    seen, first = set(), []
+    for prev, s in zip(snaps, snaps[1:]):
+        new = s.c not in seen | {prev.c}
+        seen |= {prev.c}
+        if new:
+            first.append(s)
+            assert s.compiles > prev.compiles, (prev, s)
+        else:
+            assert s.compiles == prev.compiles, (prev, s)
+    assert [s.c for s in first] == [1]     # chunk steps first, then decode
+    assert snaps[0].compiles > srv.telemetry.compiles_at_start
+
+
+def test_compile_event_tells_loads_from_compiles():
+    k = KernelCounters()
+    k.compile_event(BACKEND_COMPILE_EVENT, 2.0, fun_name="f")
+    k.compile_event(CACHE_LOAD_EVENT, 0.1)
+    k.compile_event(BACKEND_COMPILE_EVENT, 0.3, fun_name="g")
+    k.compile_event("/jax/other", 9.0)
+    assert (k.compiles, k.compile_s) == (1, 2.0)
+    assert (k.cache_loads, k.cache_load_s) == (1, 0.3)
+    assert k.compile_events == 2
+    assert [kind for _, kind, _ in k.compile_log] == ["compile",
+                                                      "cache_load"]
+    k.reset()
+    assert k.compile_events == 0 and not k.compile_log
+
+
+def test_soak_chrome_trace_phases_and_compiles(soak):
+    srv, _ = soak
+    doc = obs.chrome_trace(srv.telemetry)
+    assert obs.validate_chrome_trace(doc) == []
+    ev = doc["traceEvents"]
+    steps = [e for e in ev if e["ph"] == "X" and e["name"].startswith("step")]
+    phases = [e for e in ev if e["ph"] == "X"
+              and e["name"].startswith("serve.")]
+    assert len(phases) == len(PHASES) * len(steps)
+    # every phase slice nests inside its step's slice
+    for i, st in enumerate(steps):
+        for ph in phases[i * len(PHASES):(i + 1) * len(PHASES)]:
+            assert st["ts"] <= ph["ts"]
+            assert ph["ts"] + ph["dur"] <= st["ts"] + st["dur"] + 1e-3
+    snaps = list(srv.telemetry.snapshots)
+    compiled = [e for e in ev if e["ph"] == "i" and e["name"] == "compile"]
+    assert sum(e["args"]["compiles"] for e in compiled) == \
+        snaps[-1].compiles - srv.telemetry.compiles_at_start
+
+
+def test_soak_prometheus_compile_counters(soak):
+    srv, _ = soak
+    text = obs.prometheus_text(srv.telemetry, srv)
+    k = KERNEL_COUNTERS
+    assert f'picoram_compiles_total{{source="backend"}} {k.compiles}' \
+        in text
+    assert f'picoram_compiles_total{{source="cache"}} {k.cache_loads}' \
+        in text
+    assert 'picoram_compile_seconds_total{source="backend"}' in text

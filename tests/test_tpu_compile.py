@@ -6,12 +6,14 @@ against a described, unattached v5e topology: the four cim_mvm entries at
 the model's (K, N) matmul shapes, paged flash attention for decode (C = 1)
 and prefill (C = 16), and the fused decode write. Nothing runs; each test
 compiles one kernel and checks that the compiled program holds a Mosaic
-custom call. The topology is described inside a fixture (never at import),
-so every test worker collects the same tests and only the worker given
-this file loads the TPU compiler.
+custom call named for the kernel (`cim_mvm`, `paged_attn`, `kv_write`: the
+names a device trace shows). The topology is described inside a fixture
+(never at import), so every test worker collects the same tests and only
+the worker given this file loads the TPU compiler.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +52,13 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, name: str):
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert calls
+    # the custom call's instruction is named for its pallas_call's name=
+    assert all(re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", ln)
+               for ln in calls), calls
     return text
 
 
@@ -82,7 +88,7 @@ def test_cim_mvm_compiles(one_chip, entry, k, n, rows):
         "noisy_packed": lambda x, w, s: ops.cim_mvm_pallas_noisy_packed(
             x, w, noisy, noise_seed=s, interpret=False),
     }
-    _compile(fns[entry], x, w, seed)
+    _compile(fns[entry], x, w, seed, name="cim_mvm")
 
 
 def test_cim_mvm_full_sim_level_compiles(one_chip):
@@ -92,7 +98,7 @@ def test_cim_mvm_full_sim_level_compiles(one_chip):
                  x, w, full, noise_seed=s, inl_seed=3, interpret=False),
              _spec(one_chip, (8, 2048), jnp.float32),
              _spec(one_chip, (1024, 2048), jnp.uint8),
-             _spec(one_chip, (), jnp.int32))
+             _spec(one_chip, (), jnp.int32), name="cim_mvm")
 
 
 @pytest.mark.parametrize("kblocks,row_tile", [(1, None), (4, None), (2, 8)])
@@ -107,7 +113,7 @@ def test_paged_flash_attention_compiles(one_chip, c, kblocks, row_tile):
              _spec(one_chip, pool, jnp.bfloat16),
              _spec(one_chip, (B, MB), jnp.int32),
              _spec(one_chip, (B,), jnp.int32),
-             _spec(one_chip, (B,), jnp.int32))
+             _spec(one_chip, (B,), jnp.int32), name="paged_attn")
 
 
 def test_fused_paged_write_compiles(one_chip):
@@ -118,4 +124,4 @@ def test_fused_paged_write_compiles(one_chip):
              _spec(one_chip, pool, jnp.bfloat16),
              _spec(one_chip, (B, 1, KH, DH), jnp.bfloat16),
              _spec(one_chip, (B, 1, KH, DH), jnp.bfloat16),
-             _spec(one_chip, (B, 1), jnp.int32))
+             _spec(one_chip, (B, 1), jnp.int32), name="kv_write")
