@@ -169,8 +169,9 @@ def attn_candidates(mb: int, cg: int,
     coarser pool blocks — fewer, larger fetches per window, the knob
     `serve.py --tune-cache` feeds back into the paged-pool layout (the
     kernel itself takes the pool's pagination as given at dispatch time).
-    The default (kblocks=1, single row tile, the stated block_size) is
-    always candidate 0 so tuning can only ever tie or win."""
+    One block per step (kblocks=1, single row tile, the stated
+    block_size) is always candidate 0; every kblocks gives the same bits,
+    so the choice is one of speed alone."""
     out = [{"block_size": block_size, "kblocks": 1, "row_tile": None}]
     kb = 2
     while kb <= min(mb, 16):

@@ -26,21 +26,30 @@ exactly like layer matmuls select their CIM backend.
             (the same escape hatch the CIM engine honors for
             environments without interpret-mode Pallas)
 
-Kernel layout (grid = (B, KH, MB), MB = blocks per slot window):
+Kernel layout (grid = (B, row tiles, MB / kblocks), MB = blocks per slot
+window):
 
-  * the two leading grid axes are parallel (one program per slot × KV
-    head); the block axis is sequential ("arbitrary") and innermost so the
-    [C·G, dh] output accumulator plus the online-softmax running max/sum
-    stay resident in VMEM across all MB blocks — the same
-    revisit-nothing-in-HBM discipline as the fused CIM MVM kernel;
+  * the two leading grid axes are parallel (one program per slot × tile
+    of query rows, holding every KV head); the block axis is sequential
+    ("arbitrary") and innermost so each head's [C·G, dh] output
+    accumulator plus the online-softmax running max/sum stay resident in
+    VMEM across all MB blocks — the same revisit-nothing-in-HBM discipline
+    as the fused CIM MVM kernel;
   * the block tables (and per-slot base positions / valid lengths) ride in
     as scalar-prefetch operands: the K/V BlockSpec index maps read
-    `tables[b, j]`, so the pool block each grid step DMAs into VMEM IS the
-    slot's j-th logical block — a gather the kernel gets for free from the
-    pipeline, with no [B, W, KH, dh] windowed copy ever materialized. The
-    pools are head-major inside a block ([NB, KH, bs, dh]), so one fetch is
-    a (1, 1, bs, dh) block whose last two dims equal the pool's — the
-    block shape Mosaic accepts for any bs and dh;
+    `tables[b, j·kblocks + i]`, so the pool blocks each grid step DMAs
+    into VMEM ARE the slot's logical blocks — a gather the kernel gets for
+    free from the pipeline, with no [B, W, KH, dh] windowed copy ever
+    materialized. The pools are head-major inside a block ([NB, KH, bs,
+    dh]), so one fetch is a whole (1, KH, bs, dh) block, one contiguous
+    copy, whose last two dims equal the pool's — the block shape Mosaic
+    accepts for any bs and dh;
+  * a step folds its kblocks blocks in one after another, each head by
+    head (unrolled: the heads' independent updates interleave), with one
+    [C·G, bs] score tile per update: the same operations on the same
+    shapes as one block and one head per step, so kblocks (by default from
+    the shape, `_resolve_attn_config`) changes the grid's length, not a
+    bit of the output; blocks past the slot's valid length are skipped;
   * GQA is folded as rows: q arrives [B, KH, C·G, dh] (C = chunk width, G
     = query heads per KV head), so decode (C=1) and chunked prefill are
     the SAME kernel — the causal mask per row uses that row's chunk
@@ -174,28 +183,30 @@ def _exact_attention(q, k_pool, v_pool, tables, positions, kv_len):
 # "kernel" backend: fused Pallas flash decode/prefill over block tables
 # ---------------------------------------------------------------------------
 def _paged_attn_kernel(tables_ref, lens_ref, kvl_ref, q_ref, *refs,
-                       scale: float, block_size: int, g: int, kblocks: int,
-                       row_tile: int):
-    """One (slot b, KV head h, row tile r) program; sequential pass over
-    the MB blocks, `kblocks` logical blocks per step.
+                       scale: float, block_size: int, g: int, kblocks: int):
+    """One (slot b, row tile r) program over every KV head; sequential pass
+    over the MB blocks, `kblocks` logical blocks per step.
 
-    q_ref [1, 1, RT, dh] (RT = row tile of the C·G query rows); the step's
-    KV arrives as `kblocks` separate [1, 1, bs, dh] refs — the slot's
-    logical blocks j·kblocks … j·kblocks+kblocks−1, each fetched by its own
-    index map through the scalar-prefetched table, so the pipeline double-
-    buffers a [kblocks·bs, dh] span per sequential step. Scratch holds the
-    online-softmax state (running max m, sum l, PV accumulator) in VMEM for
-    the whole pass; the only score tensor ever live is the
-    [RT, kblocks·bs] tile of this step.
+    q_ref [1, KH, RT, dh] (RT = row tile of the C·G query rows); the step's
+    KV arrives as `kblocks` separate [1, KH, bs, dh] refs — the slot's
+    logical blocks j·kblocks … j·kblocks+kblocks−1, each fetched whole (one
+    contiguous pool block, all heads) by its own index map through the
+    scalar-prefetched table. Scratch holds each head's online-softmax state
+    (running max m, sum l, PV accumulator) in VMEM for the whole pass.
+
+    The blocks of a step are folded in one after another, and each block
+    head by head, with the same [RT, bs] tile update whatever kblocks is or
+    how many heads the program holds: the outputs are bit-identical to one
+    block and one head per grid step.
     """
     k_refs = refs[:kblocks]
     v_refs = refs[kblocks:2 * kblocks]
     o_ref = refs[2 * kblocks]
     m_ref, l_ref, acc_ref = refs[2 * kblocks + 1:]
+    kh, rt = q_ref.shape[1:3]
     b = pl.program_id(0)
-    r = pl.program_id(2)
-    j = pl.program_id(3)
-    span = kblocks * block_size
+    r = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -205,50 +216,60 @@ def _paged_attn_kernel(tables_ref, lens_ref, kvl_ref, q_ref, *refs,
 
     kvl = kvl_ref[b]
 
-    # Steps whose whole span sits at or past the slot's valid length hold
-    # nothing attendable (every position masks to weight 0) — skip their
-    # MXU work entirely; their table entries point at the trash block
-    # anyway (including the pad entries appended to make MB divide).
-    @pl.when(j * span < kvl)
-    def _block():
-        q = q_ref[0, 0].astype(jnp.float32)            # [RT, dh]
-        k = jnp.concatenate(                           # [span, dh]
-            [kr[0, 0] for kr in k_refs], axis=0).astype(jnp.float32)
-        v = jnp.concatenate(
-            [vr[0, 0] for vr in v_refs], axis=0).astype(jnp.float32)
-        rt = q.shape[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos_s = j * span \
-            + jax.lax.broadcasted_iota(jnp.int32, (rt, span), 1)
-        row = r * rt + jax.lax.broadcasted_iota(jnp.int32, (rt, span), 0)
-        chunk_off = row // g
-        pos_q = lens_ref[b] + chunk_off
-        # the paged_prefill_attention mask exactly: causal within the chunk
-        # AND inside the slot's valid window (trash/stale lanes land here)
-        mask = (pos_s <= pos_q) & (pos_s < kvl)
-        s = jnp.where(mask, s, -1e30)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # exp(-1e30 - m) underflows to 0, but force masked weights to an
-        # exact 0 so an all-masked tile cannot normalize to uniform
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)
-        # zero invalid V rows pre-dot via where (0-weight · NaN-garbage is
-        # still NaN, and so is 0 · NaN from a mask multiply)
-        pos_v = j * span \
-            + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
-        v = jnp.where(pos_v < kvl, v, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha \
-            + jnp.dot(p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    def _block(i):
+        base = (j * kblocks + i) * block_size
 
-    @pl.when(j == pl.num_programs(3) - 1)
+        # Blocks at or past the slot's valid length hold nothing attendable
+        # (every position masks to weight 0) — skip their work entirely;
+        # their table entries point at the trash block anyway (including
+        # the pad entries appended to make MB divide).
+        @pl.when(base < kvl)
+        def _update():
+            pos_s = base + jax.lax.broadcasted_iota(
+                jnp.int32, (rt, block_size), 1)
+            row = r * rt + jax.lax.broadcasted_iota(
+                jnp.int32, (rt, block_size), 0)
+            chunk_off = row // g
+            pos_q = lens_ref[b] + chunk_off
+            # the paged_prefill_attention mask exactly: causal within the
+            # chunk AND inside the slot's valid window (trash/stale lanes)
+            mask = (pos_s <= pos_q) & (pos_s < kvl)
+            pos_v = base + jax.lax.broadcasted_iota(
+                jnp.int32, (block_size, 1), 0)
+            for h in range(kh):
+                q = q_ref[0, h].astype(jnp.float32)            # [RT, dh]
+                k = k_refs[i][0, h].astype(jnp.float32)        # [bs, dh]
+                v = v_refs[i][0, h].astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask, s, -1e30)
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                # exp(-1e30 - m) underflows to 0, but force masked weights
+                # to an exact 0 so an all-masked tile cannot normalize to
+                # uniform
+                p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+                alpha = jnp.exp(m_prev - m_new)
+                # zero invalid V rows pre-dot via where (0-weight ·
+                # NaN-garbage is still NaN, and so is 0 · NaN from a mask
+                # multiply)
+                v = jnp.where(pos_v < kvl, v, 0.0)
+                l_ref[h] = l_ref[h] * alpha \
+                    + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha \
+                    + jnp.dot(p, v, preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+
+    for i in range(kblocks):
+        _block(i)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         # idle lanes (kv_len = 0) keep l = 0 → emit 0, never NaN; their
         # outputs are discarded by the scheduler anyway
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 @functools.partial(jax.jit,
@@ -269,32 +290,31 @@ def _paged_attn_call(q3, k_pool, v_pool, tables, lens, kvl, *,
     assert cg % rt == 0, (cg, rt)
     kern = functools.partial(_paged_attn_kernel,
                              scale=1.0 / math.sqrt(dh),
-                             block_size=block_size, g=g, kblocks=kblocks,
-                             row_tile=rt)
+                             block_size=block_size, g=g, kblocks=kblocks)
 
     def _kv_map(i):
         # i-th sub-block of the step's kblocks-wide span; default-arg bind
         # so each spec closes over its own stride offset
-        return lambda b, h, r, j, t, ln, kv, i=i: (t[b, j * kblocks + i],
-                                                   h, 0, 0)
+        return lambda b, r, j, t, ln, kv, i=i: (t[b, j * kblocks + i],
+                                                0, 0, 0)
 
-    kv_spec = [pl.BlockSpec((1, 1, block_size, dh), _kv_map(i))
+    kv_spec = [pl.BlockSpec((1, kh, block_size, dh), _kv_map(i))
                for i in range(kblocks)]
+    qo_spec = pl.BlockSpec((1, kh, rt, dh),
+                           lambda b, r, j, t, ln, kv: (b, 0, r, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kh, cg // rt, mb // kblocks),
+        grid=(b, cg // rt, mb // kblocks),
         in_specs=[
-            pl.BlockSpec((1, 1, rt, dh),
-                         lambda b, h, r, j, t, ln, kv: (b, h, r, 0)),
+            qo_spec,
             *kv_spec,          # kblocks K blocks …
             *kv_spec,          # … then the matching V blocks
         ],
-        out_specs=pl.BlockSpec((1, 1, rt, dh),
-                               lambda b, h, r, j, t, ln, kv: (b, h, r, 0)),
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((rt, 1), jnp.float32),    # running max m
-            pltpu.VMEM((rt, 1), jnp.float32),    # running sum l
-            pltpu.VMEM((rt, dh), jnp.float32),   # PV accumulator
+            pltpu.VMEM((kh, rt, 1), jnp.float32),    # running max m
+            pltpu.VMEM((kh, rt, 1), jnp.float32),    # running sum l
+            pltpu.VMEM((kh, rt, dh), jnp.float32),   # PV accumulator
         ],
     )
     return pl.pallas_call(
@@ -302,8 +322,7 @@ def _paged_attn_call(q3, k_pool, v_pool, tables, lens, kvl, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, cg, dh), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attn",
     )(tables.astype(jnp.int32), lens.astype(jnp.int32),
@@ -311,14 +330,28 @@ def _paged_attn_call(q3, k_pool, v_pool, tables, lens, kvl, *,
       *([k_pool] * kblocks), *([v_pool] * kblocks))
 
 
-def _resolve_attn_config(*, window: int, c: int, mb: int, cg: int):
+# Defaults for one sequential step of the kernel: the positions it covers,
+# the [C·G, bs] tile updates it unrolls (kblocks × KH; the first call's
+# trace and compile grow with them, the call's time does not), and the VMEM
+# its double-buffered K + V blocks may take.
+_ATTN_SPAN = 128
+_ATTN_UNROLL = 8
+_ATTN_KV_VMEM = 4 << 20
+
+
+def _resolve_attn_config(*, window: int, c: int, mb: int, cg: int,
+                         bs: int, kh: int, dh: int, itemsize: int):
     """(kblocks, row_tile) for this shape, tuning cache first.
 
     Consults kernels.autotune (env `REPRO_TUNE_CACHE`) under the
-    "paged_attn" kernel key and the decode/prefill shape family; a miss —
-    or no cache at all — keeps the PR-5 defaults (one block per step, one
-    row tile). Values are clamped to the actual geometry so a cache tuned
-    on a bigger shape family can never produce an invalid grid.
+    "paged_attn" kernel key and the decode/prefill shape family. A miss —
+    or no cache at all — takes kblocks from the shape: a span of about
+    `_ATTN_SPAN` positions per sequential step, capped at `_ATTN_UNROLL`
+    tile updates per step and so that the double-buffered K + V blocks
+    (2 pools × 2 buffers × kblocks × [KH, bs, dh]) fit in
+    `_ATTN_KV_VMEM`; and one row tile. Values are clamped to the actual
+    geometry so a cache tuned on a bigger shape family can never produce
+    an invalid grid.
     """
     from repro.kernels import autotune
     cfg = autotune.lookup("paged_attn",
@@ -326,14 +359,14 @@ def _resolve_attn_config(*, window: int, c: int, mb: int, cg: int):
                           backend="kernel")
     if autotune.cache_path():
         KERNEL_COUNTERS.tune_lookup("paged_attn", hit=cfg is not None)
-    kblocks = 1
-    row_tile = None
     if cfg:
         kblocks = max(1, min(int(cfg.get("kblocks", 1) or 1), mb))
         row_tile = cfg.get("row_tile")
         if row_tile:
             row_tile = max(1, min(int(row_tile), cg))
-    return kblocks, row_tile
+        return kblocks, row_tile
+    fit = _ATTN_KV_VMEM // (4 * kh * bs * dh * itemsize)
+    return max(1, min(mb, _ATTN_SPAN // bs, _ATTN_UNROLL // kh, fit)), None
 
 
 def paged_flash_attention(q, k_pool, v_pool, tables, lens, kv_len, *,
@@ -349,11 +382,14 @@ def paged_flash_attention(q, k_pool, v_pool, tables, lens, kv_len, *,
     share one kernel; pools stay in their storage dtype and are upcast
     per-block in VMEM.
 
-    kblocks / row_tile (None → tuning cache, default 1 / single tile)
-    control the pipeline shape: each sequential grid step fetches `kblocks`
-    logical KV blocks (tables are padded with trash entries to divide), and
-    the C·G query rows split into `row_tile`-high parallel tiles (rows are
-    padded with dummy queries to divide — their outputs are sliced away).
+    kblocks / row_tile control the pipeline shape: each sequential grid
+    step fetches `kblocks` logical KV blocks (tables are padded with trash
+    entries to divide), and the C·G query rows split into `row_tile`-high
+    parallel tiles (rows are padded with dummy queries to divide — their
+    outputs are sliced away). kblocks None → tuning cache, else from the
+    shape (`_resolve_attn_config`), with the cache's row tile unless one
+    is given; row_tile None with kblocks given → one tile. Every choice
+    gives the same bits.
     """
     interpret = interpret_mode(interpret)
     b, c, h, dh = q.shape
@@ -361,10 +397,13 @@ def paged_flash_attention(q, k_pool, v_pool, tables, lens, kv_len, *,
     g = h // kh
     mb = tables.shape[1]
     cg = c * g
-    if kblocks is None and row_tile is None:
-        kblocks, row_tile = _resolve_attn_config(window=mb * bs, c=c,
-                                                 mb=mb, cg=cg)
-    kblocks = max(1, min(kblocks or 1, mb))
+    if kblocks is None:
+        kblocks, tuned_rt = _resolve_attn_config(
+            window=mb * bs, c=c, mb=mb, cg=cg, bs=bs, kh=kh, dh=dh,
+            itemsize=jnp.dtype(k_pool.dtype).itemsize)
+        if row_tile is None:
+            row_tile = tuned_rt
+    kblocks = max(1, min(kblocks, mb))
     if row_tile is not None and (row_tile <= 0 or row_tile >= cg):
         row_tile = None
     if mb % kblocks:

@@ -23,6 +23,12 @@ needs_pallas = pytest.mark.skipif(
                     "jnp-only")
 
 
+# the pool geometry behind each window below: blocks of 8 positions, eight
+# KV heads of 32, float32; eight heads fill a step, so a miss takes
+# kblocks = 1
+_GEOM = dict(bs=8, kh=8, dh=32, itemsize=4)
+
+
 def _write_cache(path, entries):
     doc = autotune.save_cache(str(path), entries)
     assert doc["schema"] == autotune.CACHE_SCHEMA
@@ -56,10 +62,11 @@ def test_attn_dispatch_picks_tuned_config(tmp_path, monkeypatch):
     })
     monkeypatch.setenv(autotune.CACHE_ENV, path)
     # decode window 40 buckets to w64 → tuned hit, clamped to the geometry
-    kb, rt = pa._resolve_attn_config(window=40, c=1, mb=5, cg=2)
+    kb, rt = pa._resolve_attn_config(window=40, c=1, mb=5, cg=2, **_GEOM)
     assert (kb, rt) == (4, None)
     # prefill family has no entry → defaults
-    assert pa._resolve_attn_config(window=40, c=8, mb=5, cg=16) == (1, None)
+    assert pa._resolve_attn_config(window=40, c=8, mb=5, cg=16,
+                                   **_GEOM) == (1, None)
 
 
 def test_attn_tuned_config_clamped_to_geometry(tmp_path, monkeypatch):
@@ -68,7 +75,7 @@ def test_attn_tuned_config_clamped_to_geometry(tmp_path, monkeypatch):
             {"kblocks": 64, "row_tile": 999},
     })
     monkeypatch.setenv(autotune.CACHE_ENV, path)
-    kb, rt = pa._resolve_attn_config(window=64, c=1, mb=3, cg=4)
+    kb, rt = pa._resolve_attn_config(window=64, c=1, mb=3, cg=4, **_GEOM)
     assert kb == 3 and rt == 4
 
 
@@ -93,7 +100,8 @@ def test_platform_mismatch_is_a_miss(tmp_path, monkeypatch):
                            platform="tpu-v9"): {"kblocks": 8},
     })
     monkeypatch.setenv(autotune.CACHE_ENV, path)
-    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2) == (1, None)
+    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2,
+                                   **_GEOM) == (1, None)
 
 
 def test_cache_reloads_on_rewrite(tmp_path, monkeypatch):
@@ -101,10 +109,12 @@ def test_cache_reloads_on_rewrite(tmp_path, monkeypatch):
     key = autotune.cache_key("paged_attn", "decode_w64", "kernel")
     path = _write_cache(tmp_path / "tc.json", {key: {"kblocks": 2}})
     monkeypatch.setenv(autotune.CACHE_ENV, path)
-    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2)[0] == 2
+    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2,
+                                   **_GEOM)[0] == 2
     os.utime(_write_cache(tmp_path / "tc.json", {key: {"kblocks": 8}}),
              (1e9, 1e9))  # force a distinct mtime even on coarse clocks
-    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2)[0] == 8
+    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2,
+                                   **_GEOM)[0] == 8
 
 
 @needs_pallas
@@ -132,7 +142,8 @@ def test_tuned_attn_end_to_end_matches_exact(tmp_path, monkeypatch):
 def test_missing_cache_file_is_empty(monkeypatch):
     monkeypatch.setenv(autotune.CACHE_ENV, "/nonexistent/tune.json")
     assert autotune.load_cache() == {}
-    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2) == (1, None)
+    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2,
+                                   **_GEOM) == (1, None)
 
 
 def test_no_env_is_empty(monkeypatch):
@@ -146,7 +157,8 @@ def test_malformed_json_falls_back(tmp_path, monkeypatch):
     monkeypatch.setenv(autotune.CACHE_ENV, str(p))
     with pytest.warns(UserWarning, match="ignoring tune cache"):
         assert autotune.load_cache() == {}
-    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2) == (1, None)
+    assert pa._resolve_attn_config(window=64, c=1, mb=8, cg=2,
+                                   **_GEOM) == (1, None)
 
 
 def test_stale_schema_falls_back(tmp_path, monkeypatch):

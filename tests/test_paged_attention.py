@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import autotune
 from repro.kernels import paged_attention as pa
 from repro.parallel import sharding
 
@@ -195,22 +196,33 @@ def test_stale_block_tail_poison_invariance():
 # ---------------------------------------------------------------------------
 # multi-block pipeline (kblocks > 1) and wide row tiles
 # ---------------------------------------------------------------------------
+# (c, kblocks, kh): decode and chunk steps at kblocks 2, 4 and 8, and
+# programs holding four KV heads
+_KBLOCKS_CASES = [(c, kb, 2) for c in (1, 4) for kb in (2, 4, 8)] \
+    + [(1, 8, 4), (4, 8, 4)]
+
+
 @needs_pallas
-@pytest.mark.parametrize("kblocks", [2, 4])
-@pytest.mark.parametrize("c", [1, 4])
-def test_kblocks_parity(kblocks, c):
-    """Fetching kblocks KV blocks per sequential grid step must match the
-    single-block pipeline AND the exact backend at mixed depths, for both
-    decode and chunked prefill. mb=5 is not divisible by 2 or 4, so the
+@pytest.mark.parametrize(
+    "c,kblocks,kh",
+    [pytest.param(*p, id=f"{p[0]}-{p[1]}" + (f"-kh{p[2]}" if p[2] != 2
+                                              else ""))
+     for p in _KBLOCKS_CASES])
+def test_kblocks_parity(c, kblocks, kh):
+    """Fetching kblocks KV blocks per sequential grid step must give the
+    single-block pipeline's output bit for bit (the blocks of a step are
+    folded in one after another, head by head) and match the exact backend
+    at mixed depths, for both decode and chunked prefill, with G = 2 query
+    heads per KV head. mb=9 is not divisible by 2, 4 or 8, so the
     block-table padding (trash block 0 on the tail) is exercised too."""
-    case = _make_case(53 + kblocks + c, b=3, mb=5, c=c)
+    case = _make_case(53 + kblocks + c, b=3, kh=kh, g=2, mb=9, c=c)
     q, kp, vp, tables, positions, kvl = case
     lens = kvl - c
     o_one = pa.paged_flash_attention(q, kp, vp, tables, lens, kvl, kblocks=1)
     o_multi = pa.paged_flash_attention(q, kp, vp, tables, lens, kvl,
                                        kblocks=kblocks)
     o_exact = _run("exact", case)
-    assert jnp.allclose(o_multi, o_one, atol=2e-6, rtol=2e-6), \
+    assert jnp.array_equal(o_multi, o_one), \
         float(jnp.max(jnp.abs(o_multi - o_one)))
     assert jnp.allclose(o_multi, o_exact, atol=2e-5, rtol=2e-5)
 
@@ -219,16 +231,47 @@ def test_kblocks_parity(kblocks, c):
 @pytest.mark.parametrize("row_tile", [3, 4])
 def test_row_tile_parity(row_tile):
     """Wider C·G row tiles (dividing and non-dividing — the latter pads the
-    folded q rows) agree with the single-tile kernel and exact."""
+    folded q rows) give the single-tile, single-block kernel's output bit
+    for bit, and agree with exact."""
     case = _make_case(59 + row_tile, b=2, kh=2, g=2, mb=6, c=4)  # cg = 8
     q, kp, vp, tables, positions, kvl = case
     lens = kvl - 4
-    o_one = pa.paged_flash_attention(q, kp, vp, tables, lens, kvl)
+    o_one = pa.paged_flash_attention(q, kp, vp, tables, lens, kvl, kblocks=1)
     o_tiled = pa.paged_flash_attention(q, kp, vp, tables, lens, kvl,
                                        kblocks=2, row_tile=row_tile)
-    assert jnp.allclose(o_tiled, o_one, atol=2e-6, rtol=2e-6), \
+    assert jnp.array_equal(o_tiled, o_one), \
         float(jnp.max(jnp.abs(o_tiled - o_one)))
     assert jnp.allclose(o_tiled, _run("exact", case), atol=2e-5, rtol=2e-5)
+
+
+def test_default_kblocks_from_shape(monkeypatch):
+    """With no tuning cache, kblocks spans about 128 positions, unrolls at
+    most 8 tile updates (kblocks × KH) a step, clamps to a short table, and
+    keeps the double-buffered K + V blocks within the VMEM cap; one row
+    tile."""
+    monkeypatch.delenv(autotune.CACHE_ENV, raising=False)
+    # internlm2-1.8b in bf16: 8 KV heads fill the step with one block
+    geom = dict(bs=16, kh=8, dh=128, itemsize=2)
+    for c, cg in ((1, 2), (16, 32)):
+        assert pa._resolve_attn_config(window=2048, c=c, mb=128, cg=cg,
+                                       **geom) == (1, None)
+    # one KV head: 8 blocks of 16, a 128-position span; 2 heads: 4 blocks
+    one = dict(geom, kh=1)
+    assert pa._resolve_attn_config(window=2048, c=1, mb=128, cg=8,
+                                   **one) == (8, None)
+    assert pa._resolve_attn_config(window=2048, c=1, mb=128, cg=8,
+                                   **dict(geom, kh=2)) == (4, None)
+    # a short table clamps to its geometry
+    assert pa._resolve_attn_config(window=80, c=1, mb=5, cg=8,
+                                   **one) == (5, None)
+    # one float32 head of 4096: 256 KiB a block, so four blocks a step
+    wide = dict(bs=16, kh=1, dh=4096, itemsize=4)
+    kb, _ = pa._resolve_attn_config(window=2048, c=1, mb=128, cg=8, **wide)
+    assert kb == 4
+    assert 2 * 2 * kb * 1 * 16 * 4096 * 4 <= pa._ATTN_KV_VMEM
+    # blocks wider than the span: one block a step
+    assert pa._resolve_attn_config(window=2048, c=1, mb=8, cg=8, bs=256,
+                                   kh=1, dh=128, itemsize=2) == (1, None)
 
 
 @needs_pallas

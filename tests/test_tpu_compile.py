@@ -101,7 +101,9 @@ def test_cim_mvm_full_sim_level_compiles(one_chip):
              _spec(one_chip, (), jnp.int32), name="cim_mvm")
 
 
-@pytest.mark.parametrize("kblocks,row_tile", [(1, None), (4, None), (2, 8)])
+# kblocks None resolves from the shape (8 blocks, a 128-position span)
+@pytest.mark.parametrize("kblocks,row_tile",
+                         [(1, None), (4, None), (2, 8), (None, None)])
 @pytest.mark.parametrize("c", [1, 16])
 def test_paged_flash_attention_compiles(one_chip, c, kblocks, row_tile):
     pool = (B * MB + 1, KH, BS, DH)
